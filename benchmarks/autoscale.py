@@ -188,14 +188,17 @@ def bench_idle_tick_stats(polls: int = 200,
 
 
 def run(mode: str = "all") -> List[Dict]:
+    # The process cycle runs first: it forks shard processes from this one,
+    # and the thread cycle's batch-plane workers initialize JAX here (on a
+    # TPU host, taking the chip) — no child may inherit that runtime.
     rows: List[Dict] = []
-    if mode in ("all", "thread"):
-        rows.append(bench_fig8("thread"))
     if mode in ("all", "process"):
         rows.append(bench_fig8(
             "process", n_events=20_000, subjects=16, partitions=4,
             batch_size=1024, events_per_shard=2_000, max_shards=2,
             grace=0.5, poll=0.05))
+    if mode in ("all", "thread"):
+        rows.append(bench_fig8("thread"))
     rows.append(bench_idle_tick_stats())
     return rows
 

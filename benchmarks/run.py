@@ -4,15 +4,22 @@ Prints ``name,us_per_call,derived`` CSV (one line per measurement) and writes
 the raw rows to results/benchmarks.json.
 
     PYTHONPATH=src python -m benchmarks.run [--only load_test,overhead]
+
+Each suite runs in a process of its own, started with ``spawn``: a suite's
+batch-plane workers initialize JAX (on a TPU host that process takes the
+chip), and suites such as ``sharded_load`` and ``autoscale`` fork shard
+processes, which must not inherit a JAX runtime.  This process stays off
+JAX altogether.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import multiprocessing as mp
 import os
 import sys
-import time
 import traceback
+from concurrent.futures import ProcessPoolExecutor
 
 SUITES = [
     ("load_test", "Table 1 — events/s per worker"),
@@ -32,24 +39,40 @@ SUITES = [
 ]
 
 
+def _run_suite(suite: str):
+    """One suite's rows, computed in the suite's own process."""
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    mod = __import__(f"benchmarks.{suite}", fromlist=["run"])
+    return mod.run()
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated suite names")
     args = ap.parse_args()
-    chosen = args.only.split(",") if args.only else [s for s, _ in SUITES]
+    known = [s for s, _ in SUITES]
+    chosen = args.only.split(",") if args.only else known
+    unknown = sorted(set(chosen) - set(known))
+    if unknown:
+        ap.error(f"unknown suite(s): {', '.join(unknown)}; "
+                 f"choose from {', '.join(known)}")
 
+    ctx = mp.get_context("spawn")
     all_rows = []
     failures = 0
     print("name,us_per_call,derived")
-    for suite, desc in SUITES:
+    for suite in known:
         if suite not in chosen:
             continue
-        mod = __import__(f"benchmarks.{suite}", fromlist=["run"])
-        t0 = time.time()
         try:
-            rows = mod.run()
-        except Exception:  # noqa: BLE001
+            # executor workers are not daemonic, so a suite may start its
+            # own shard processes
+            with ProcessPoolExecutor(1, mp_context=ctx) as ex:
+                rows = ex.submit(_run_suite, suite).result()
+        except Exception:  # noqa: BLE001 - one failed suite must not stop the rest
             traceback.print_exc()
             print(f"{suite}.FAILED,0,see stderr")
             failures += 1

@@ -173,13 +173,19 @@ def run(reps: int = 3, n_events: int = 100_000,
     # Interleave scenarios across repetitions and keep the best events/s per
     # scenario: single-run numbers on small shared machines swing ±25% from
     # CPU steal, which would drown the architectural deltas being measured.
+    #
+    # Process rows are measured first: their shard processes fork from this
+    # one, and the thread rows' batch-plane workers initialize JAX here (on
+    # a TPU host, taking the chip) — no child may inherit that runtime.
+    proc_best = (_measure_process(reps, n_events)
+                 if mode in ("all", "process") else None)
     rows: List[Dict] = []
     if mode in ("all", "thread"):
         rows.extend(_run_thread(reps, n_events))
-    if mode in ("all", "process"):
+    if proc_best is not None:
         thread4 = next((r["events_per_s"] for r in rows
                         if r["name"] == "sharded_load.noop_4shard"), None)
-        rows.extend(_run_process(reps, n_events, thread4_noop=thread4))
+        rows.extend(_process_rows(proc_best, thread4_noop=thread4))
     return rows
 
 
@@ -265,19 +271,24 @@ def _run_thread(reps: int, n_events: int) -> List[Dict]:
     return rows
 
 
-def _run_process(reps: int, n_events: int,
-                 thread4_noop: float = None) -> List[Dict]:
-    """Process-mode rows: the same noop workload on ``ProcessShardPool``
-    over the durable file bus.  Reports wall events/s, the ratio against the
-    threaded 4-shard row (when available), per-count scaling vs 1 process,
-    and the aggregate shard-CPU seconds (cpu ≈ wall·shards ⇒ the kernel ran
-    the processes in parallel; cpu ≈ wall ⇒ it serialized them)."""
+def _measure_process(reps: int, n_events: int) -> Dict[int, Dict]:
+    """Best-of-``reps`` process-mode run per shard count: the same noop
+    workload on ``ProcessShardPool`` over the durable file bus."""
     best: Dict[int, Dict] = {}
     for _ in range(reps):
         for shards in PROC_SHARD_COUNTS:
             r = bench_proc_noop(n_events=n_events, shards=shards)
             if shards not in best or r["events_per_s"] > best[shards]["events_per_s"]:
                 best[shards] = r
+    return best
+
+
+def _process_rows(best: Dict[int, Dict],
+                  thread4_noop: float = None) -> List[Dict]:
+    """Process-mode rows.  Reports wall events/s, the ratio against the
+    threaded 4-shard row (when available), per-count scaling vs 1 process,
+    and the aggregate shard-CPU seconds (cpu ≈ wall·shards ⇒ the kernel ran
+    the processes in parallel; cpu ≈ wall ⇒ it serialized them)."""
     rows: List[Dict] = []
     base = best[PROC_SHARD_COUNTS[0]]["events_per_s"]
     for shards in PROC_SHARD_COUNTS:

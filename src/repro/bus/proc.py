@@ -127,12 +127,16 @@ def _shard_main(member: str, workflow: str, bus_root: str, state_root: str,
         sample = 1.0 if cfg["trace"] == "full" else cfg.get("trace_sample", 0.1)
         tracer = Tracer(sample=sample, collector=SpanCollector(segment=seg),
                         tag=member)
+    # A shard process owns no device: a chip belongs to one process, and a
+    # forked child must never touch a JAX runtime copied from its parent.
+    # So it joins on the host path, named here rather than left to ``auto``
+    # (which would initialize JAX), and reported as tf_join_backend_numpy.
     worker = ShardWorker(
         member, workflow, store, state, backend,
         batch_size=cfg["batch_size"], commit_policy=cfg["commit_policy"],
         keep_event_log=False, timers=None, partitions=(),
         batch_plane=cfg["batch_plane"], action_plane=cfg["action_plane"],
-        metrics=cfg.get("metrics", True), tracer=tracer,
+        vector_join="numpy", metrics=cfg.get("metrics", True), tracer=tracer,
     )
     conn.send(("ready", member))
     poll = cfg["poll"]

@@ -13,8 +13,8 @@ the pre-extracted result columns.
 machinery: the batch is bucketed per subject C-level (one pass), each
 distinct subject is screened against its compiled dispatch entries, all
 claimed subjects are folded into one one-hot segmented sum over the routed
-event batch — the ``event_join`` kernel (Pallas on TPU, jitted-jnp or
-``bincount`` on CPU; see ``kernels.event_join.dispatch``) — and aggregating
+event batch — the ``event_join`` kernel (Pallas on TPU, ``bincount`` on the
+host; see ``kernels.event_join.dispatch``) — and aggregating
 triggers additionally get their ``data["result"]`` column appended in one
 list-comprehension per (subject, trigger) run.  The Table-1 join hot loop
 becomes O(batch) array/column ops plus O(distinct subjects) Python.

@@ -271,19 +271,19 @@ class TFWorker:
         # evaluate→fire loop into one fire-run condition call + one batched
         # action call.  Only effective on the batch plane.
         self.action_plane = action_plane
+        # The vector join plane's backend ("pallas" | "jax" | "numpy" |
+        # "off"), exported as the ``tf_join_backend_<name>`` gauge so a run
+        # shows which one joined its batches; see kernels.event_join.dispatch
+        # for how ``auto`` resolves.
         self._vector_plane = None
+        self.join_backend = "off"
         if batch_plane:
             mode = vector_join or os.environ.get("TRIGGERFLOW_JOIN_BACKEND", "auto")
             if mode != "off":
-                try:
-                    from .batch import VectorJoinPlane
+                from .batch import VectorJoinPlane
 
-                    self._vector_plane = VectorJoinPlane(backend=mode)
-                except Exception:  # noqa: BLE001
-                    if mode != "auto":
-                        # an explicitly requested backend must fail loudly
-                        raise
-                    self._vector_plane = None  # auto: numpy missing, plane off
+                self._vector_plane = VectorJoinPlane(backend=mode)
+                self.join_backend = self._vector_plane.backend
 
         self._recover()
 
@@ -416,6 +416,11 @@ class TFWorker:
                 if self._metrics is not None else empty_snapshot())
         fold_counters(snap, {f"tf_{k}_total": v
                              for k, v in self.stats.snapshot().items()})
+        plane = self._vector_plane
+        if plane is not None:
+            fold_counters(snap, {"tf_join_calls_total": plane.calls,
+                                 "tf_join_events_total": plane.events})
+        snap["gauges"][f"tf_join_backend_{self.join_backend}"] = 1
         return snap
 
     def set_result(self, value: Any) -> None:

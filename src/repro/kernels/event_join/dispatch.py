@@ -4,19 +4,20 @@ The worker's batch plane reduces a routed event batch to per-trigger
 activation counts (``new_counts``) and threshold-crossing flags (``fired``).
 Three interchangeable backends compute the same one-hot segmented sum:
 
-* ``pallas`` — the TPU kernel (``event_join.event_join_counts``), used when a
-  TPU is attached;
+* ``pallas`` — the TPU kernel (``event_join.event_join_counts``);
 * ``jax``    — the jitted pure-jnp oracle (``ref.join_counts_ref``), the
   CPU/GPU XLA path;
-* ``numpy``  — ``np.bincount``, dependency-light fallback when JAX is absent
-  (or for tiny batches where XLA dispatch overhead dominates).
+* ``numpy``  — ``np.bincount``, the host path: for processes that own no
+  device (``ProcessShardPool`` shard processes) and hosts without a TPU.
 
-``join_counts(events, counts, expected)`` takes int32 numpy arrays
-(``events`` holds trigger row ids, −1 = padding) and returns numpy
-``(new_counts, fired)``.  Selection: ``TRIGGERFLOW_JOIN_BACKEND`` env var
-(``auto`` | ``numpy`` | ``jax`` | ``pallas`` | ``off``), default ``auto`` =
-pallas on TPU, numpy otherwise (measured faster than XLA dispatch for the
-≤4k-event batches the worker consumes).
+A backend takes int32 numpy arrays (``events`` holds trigger row ids, −1 =
+padding) and returns numpy ``(new_counts, fired)``.  Selection:
+``TRIGGERFLOW_JOIN_BACKEND`` env var (``auto`` | ``numpy`` | ``jax`` |
+``pallas`` | ``off``), default ``auto`` = pallas when this process's default
+JAX device is a TPU, numpy otherwise.  Resolving ``auto`` imports JAX and,
+unless ``JAX_PLATFORMS`` already excludes the TPU, initializes its backend:
+on a TPU host the resolving process takes the chip, so a process that must
+stay off it asks for ``numpy`` by name.
 """
 from __future__ import annotations
 
@@ -62,18 +63,19 @@ def _make_pallas_join() -> JoinFn:
 
 
 def _on_tpu() -> bool:
-    # Only consult jax if something else already paid its import cost:
-    # importing (and device-initializing) jax here would add seconds to
-    # worker startup on CPU-only hosts just to learn there is no TPU.
-    import sys
-
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
+    """Whether this process's default JAX device is a TPU.  Without JAX
+    installed there is no device to find, and a ``jax_platforms`` setting
+    (``JAX_PLATFORMS``) without ``tpu`` rules it out before any runtime
+    starts; any other error (a chip held by another process, a broken
+    runtime) propagates."""
     try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001 - not initializable
+        import jax
+    except ImportError:
         return False
+    platforms = jax.config.jax_platforms
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 _resolved: dict = {}
@@ -83,7 +85,8 @@ def resolve_join_backend(name: Optional[str] = None) -> Tuple[str, Optional[Join
     """Resolve a backend name to ``(resolved_name, fn)``, cached per name.
 
     ``fn`` is ``None`` for ``off``.  Unavailable explicit choices raise so
-    misconfiguration is loud; ``auto`` silently degrades to numpy."""
+    misconfiguration is loud; ``auto`` names what it resolved to in the
+    first element."""
     name = (name or os.environ.get("TRIGGERFLOW_JOIN_BACKEND", "auto")).lower()
     cached = _resolved.get(name)
     if cached is not None:
@@ -104,17 +107,6 @@ def resolve_join_backend(name: Optional[str] = None) -> Tuple[str, Optional[Join
         resolved = ("numpy", _numpy_join)
     _resolved[name] = resolved
     return resolved
-
-
-def join_counts(events: np.ndarray, counts: np.ndarray,
-                expected: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Segmented-sum join on the default backend (resolved once, cached by
-    ``resolve_join_backend``).  Raises when the backend is ``off`` — callers
-    that can degrade (the worker's vector plane) handle that at setup."""
-    _name, fn = resolve_join_backend()
-    if fn is None:
-        raise RuntimeError("join backend disabled (TRIGGERFLOW_JOIN_BACKEND=off)")
-    return fn(events, counts, expected)
 
 
 def join_counts_segments(lens, counts: np.ndarray, expected: np.ndarray,

@@ -22,9 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed TPUCompilerParams -> CompilerParams across JAX releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _join_kernel(events_ref, counts_ref, expected_ref, new_counts_ref,
                  fired_ref, acc_scr, *, n_blocks: int, block_events: int,
@@ -75,7 +72,7 @@ def event_join_counts(events, counts, expected, *, block_events: int = 1024,
             jax.ShapeDtypeStruct((T,), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((T,), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(events, counts, expected)

@@ -28,9 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed TPUCompilerParams -> CompilerParams across JAX releases.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, y_ref, state_ref,
                 h_scr, *, chunk: int, n_chunks: int):
@@ -109,7 +106,7 @@ def ssd_scan(x, dt, Bm, Cm, a, *, chunk: int = 128, interpret: bool = False):
             jax.ShapeDtypeStruct((BH, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, Bm, Cm, a)

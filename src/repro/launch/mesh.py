@@ -3,44 +3,21 @@
 Kept as functions (never module-level constants) so importing this module
 never touches jax device state — required because the dry-run must set
 XLA_FLAGS before any device initialization.
-
-``AxisType`` / ``axis_types=`` only exist on newer JAX; on older releases
-(e.g. 0.4.x) meshes are implicitly "auto" so dropping the kwarg is
-semantically equivalent.  ``compat_make_mesh`` is the version-safe entry
-point used here and by the tests.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # JAX >= 0.5: explicit Auto/Explicit axis types
-    from jax.sharding import AxisType
-
-    _HAS_AXIS_TYPES = True
-except ImportError:  # pragma: no cover - depends on installed JAX
-    AxisType = None
-    _HAS_AXIS_TYPES = False
-
-
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where supported, plain otherwise."""
-    if _HAS_AXIS_TYPES:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; multi_pod adds a 2-pod leading axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Whatever this host actually has (smoke tests / examples)."""
     n = len(jax.devices())
-    return compat_make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))

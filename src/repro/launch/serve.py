@@ -1,6 +1,8 @@
 """Serving launcher: trigger-batched generation with scale-to-zero.
 
     python -m repro.launch.serve --arch yi-9b --smoke --requests 8
+
+Exits non-zero when fewer requests were served than submitted.
 """
 from __future__ import annotations
 
@@ -9,6 +11,7 @@ import time
 
 from repro.configs import ARCHS, get_config
 from repro.core import KedaAutoscaler, Triggerflow
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.engine import ServingEngine
 
 
@@ -24,6 +27,7 @@ def main() -> None:
                          "PREFIX.prom (Prometheus text) and PREFIX.json")
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     tf = Triggerflow(inline_functions=True)
     eng = ServingEngine(cfg, tf, "serve", max_batch=args.max_batch,
@@ -53,6 +57,8 @@ def main() -> None:
             for path in dump_metrics(snap, args.metrics_dump):
                 print(f"metrics dumped to {path}")
         tf.shutdown()
+    if eng.served < args.requests:
+        raise SystemExit(f"served {eng.served} of {args.requests} requests")
 
 
 if __name__ == "__main__":

@@ -3,16 +3,19 @@
     python -m repro.launch.train --arch llama3.2-3b --steps 100 \
         [--smoke] [--workdir DIR] [--batch 8 --seq 256] [--accum 1]
 
-On this CPU container use --smoke (reduced config).  On a real TPU slice the
-full config shards over the production mesh; the training loop itself is a
-Triggerflow state-machine workflow (checkpoint/resume per chunk, event-replay
-fault tolerance) — kill and relaunch to resume.
+On a host without an accelerator use --smoke (reduced config).  Training
+runs on one device: the trainer builds no mesh, so the full config is not
+sharded over the production mesh (``launch/mesh.py`` serves the dry-run
+tooling only).  The training loop itself is a Triggerflow state-machine
+workflow (checkpoint/resume per chunk, event-replay fault tolerance) — kill
+and relaunch to resume.
 """
 from __future__ import annotations
 
 import argparse
 
 from repro.configs import ARCHS, get_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.training.trainer import run_training
 
 
@@ -29,6 +32,7 @@ def main() -> None:
     ap.add_argument("--workdir", default=None)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     workdir = args.workdir or f"/tmp/tf-train-{cfg.arch}"
     print(f"arch={cfg.arch} params={cfg.param_count() / 1e6:.1f}M "

@@ -33,7 +33,10 @@ class ServingEngine:
         self.max_new_tokens = max_new_tokens
         self.max_len = max_len
         self.model = Model(cfg)
-        self.params = unbox(self.model.init(jax.random.PRNGKey(0)))
+        # one compiled program: at full width, op-by-op init would hold each
+        # stacked layer weight in f32 beside its bf16 copy on the device
+        self.params = jax.jit(lambda key: unbox(self.model.init(key)))(
+            jax.random.PRNGKey(0))
         self._prefill = jax.jit(lambda p, b: self.model.prefill(p, b, max_len=max_len))
         self._decode = jax.jit(self.model.decode)
         self.served = 0

@@ -498,6 +498,36 @@ def test_join_backends_agree():
     assert (nc_np == nc_jx).all() and (f_np == f_jx).all()
 
 
+@pytest.mark.parametrize("vector_join,backend", [(None, "numpy"),
+                                                 ("off", "off")])
+def test_join_backend_is_recorded(vector_join, backend):
+    """Which backend joins a worker's batches shows in its scrape; ``auto``
+    (the default) resolves to the host path where no TPU is present."""
+    w = _mk_worker(vector_join=vector_join)
+    assert w.join_backend == backend
+    assert w.metrics_snapshot()["gauges"] == {f"tf_join_backend_{backend}": 1}
+
+
+def test_tpu_probe_propagates_device_errors(monkeypatch):
+    """A device that fails to initialize is an error, not "no TPU": the
+    probe must not quietly send the join to the host."""
+    import jax
+
+    from repro.kernels.event_join import dispatch
+
+    def broken():
+        raise RuntimeError("TPU is held by another process")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    saved = jax.config.jax_platforms
+    jax.config.update("jax_platforms", None)
+    try:
+        with pytest.raises(RuntimeError, match="held by another process"):
+            dispatch._on_tpu()
+    finally:
+        jax.config.update("jax_platforms", saved)
+
+
 # -- incremental checkpointing -------------------------------------------------
 
 def test_delta_checkpoint_equals_full_rewrite(tmp_path):

@@ -3,18 +3,19 @@ parsing, and a real (host-sized) mesh lowering with constraints applied."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs import get_config
 from repro.distributed.hlo_analysis import (ICI_BW, PEAK_FLOPS, collective_bytes,
                                             roofline_terms)
 from repro.distributed.sharding import Resolver
-from repro.launch.mesh import compat_make_mesh, make_host_mesh
+from repro.launch.mesh import make_host_mesh
 
 
 def _resolver(arch="granite-20b"):
     cfg = get_config(arch)
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     r = Resolver(cfg, mesh)
     r.sizes = {"data": 16, "model": 16}  # pretend production sizes
     return r
@@ -31,7 +32,8 @@ def test_resolver_divisibility_drops_axis():
 
 def test_resolver_batch_axes_multi_pod():
     cfg = get_config("yi-9b")
-    mesh = compat_make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     r = Resolver(cfg, mesh)
     r.sizes = {"pod": 2, "data": 16, "model": 16}
     assert r.spec(("batch", None), (256, 4096)) == P(("pod", "data"), None)

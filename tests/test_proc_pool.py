@@ -118,6 +118,46 @@ def test_thread_process_parity_join(tmp_path):
         assert proc_ctx[i].get("count") == per_subj == thread_ctx[i].get("count")
 
 
+def _register_jax_probe(backend):
+    """Shard-process init: an action that records whether the process has
+    imported JAX by the time its join fires."""
+    import sys
+
+    def probe(ctx, event, params):
+        ctx["jax_imported"] = "jax" in sys.modules
+
+    register_action("jax_probe", probe)
+
+
+def test_shard_process_joins_on_host_path_without_jax(tmp_path):
+    """A shard process owns no device: its vector join plane runs the numpy
+    backend, says so in the scrape, and never imports JAX (a spawned child
+    starts from a fresh interpreter, so nothing is inherited).  Each
+    partition interleaves eight joins over several batches, so the plane
+    claims the batches in which none can fire."""
+    n_subj, per_subj = 64, 100
+    pool = _proc_pool(tmp_path, start_method="spawn",
+                      child_init=_register_jax_probe)
+    pool.create_workflow("w")
+    for i in range(n_subj):
+        pool.add_trigger("w", make_trigger(
+            f"s{i}", condition={"name": "counter", "expected": per_subj,
+                                "aggregate": False},
+            action={"name": "jax_probe"}, trigger_id=f"t{i}",
+            transient=False))
+    pool.publish_batch("w", [termination_event(f"s{i % n_subj}", i)
+                             for i in range(n_subj * per_subj)])
+    pool.start_shards("w", 2)
+    pool.wait_drained("w", timeout=60)
+    obs = pool.obs_snapshot("w")
+    ctxs = [pool.trigger_context("w", f"t{i}") for i in range(n_subj)]
+    pool.stop_all()
+
+    assert obs["gauges"].get("tf_join_backend_numpy") == 2, obs["gauges"]
+    assert obs["counters"]["tf_join_calls_total"] > 0
+    assert [c.get("jax_imported") for c in ctxs] == [False] * n_subj
+
+
 # -- crash recovery over the durable bus -----------------------------------------
 
 def test_sigkill_crash_recovery_exactly_once(tmp_path):

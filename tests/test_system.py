@@ -82,3 +82,26 @@ def test_trigger_orchestrated_serving_batches():
         toks = e.data["result"]["tokens"]
         assert len(toks) == 3
         assert all(0 <= t < eng.cfg.vocab for t in toks)
+
+
+def test_compile_cache_dir_from_env_else_fixed_in_checkout(monkeypatch,
+                                                           tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and the code then sets nothing;
+    without it the cache sits at one fixed path inside the checkout."""
+    from pathlib import Path
+
+    import jax
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    saved = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert use_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == saved
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert Path(path) == Path(__file__).resolve().parents[1] / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", saved)
