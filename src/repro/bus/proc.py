@@ -49,6 +49,7 @@ from ..core.statestore import FileStateStore
 from ..core.triggers import Trigger
 from ..core.worker import WorkerStats
 from ..obs.metrics import empty_snapshot, fold_counters, merge_snapshot
+from ..obs.trace import arm_profiler_spans
 from .group import ConsumerGroup
 from .partitioned import FilePartitionedEventStore
 from .pool import ShardWorker
@@ -130,7 +131,9 @@ def _shard_main(member: str, workflow: str, bus_root: str, state_root: str,
     # A shard process owns no device: a chip belongs to one process, and a
     # forked child must never touch a JAX runtime copied from its parent.
     # So it joins on the host path, named here rather than left to ``auto``
-    # (which would initialize JAX), and reported as tf_join_backend_numpy.
+    # (which would initialize JAX), and reported as tf_join_backend_numpy;
+    # and profiler spans a forked child inherited armed are disarmed.
+    arm_profiler_spans(False)
     worker = ShardWorker(
         member, workflow, store, state, backend,
         batch_size=cfg["batch_size"], commit_policy=cfg["commit_policy"],

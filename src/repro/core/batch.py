@@ -58,21 +58,28 @@ CLAIMABLE_CONDITIONS = ("counter", "threshold_join")
 class VectorJoinPlane:
     """Batch-level accelerator for non-firing aggregation-join batches."""
 
-    def __init__(self, backend: Optional[str] = None, min_subjects: int = 2):
+    def __init__(self, backend: Optional[str] = None, min_subjects: int = 2,
+                 metrics=None):
         if np is None:
             raise RuntimeError("VectorJoinPlane requires numpy")
-        from ..kernels.event_join.dispatch import (join_counts_segments,
+        from ..kernels.event_join.dispatch import (LAST_CALL,
+                                                   join_counts_segments,
                                                    resolve_join_backend)
 
+        # bound from the module now, so a wrapper installed before the plane
+        # is built (a benchmark's note of calls) sees every call
         self._join_segments = join_counts_segments
+        self._last_call = LAST_CALL
         self.backend, self._join = resolve_join_backend(backend)
         if self._join is None:
             raise RuntimeError("join backend disabled")
         # Below this many claimable subjects the per-trigger batched
         # conditions beat array assembly.
         self.min_subjects = min_subjects
-        self.calls = 0
-        self.events = 0
+        # the shard's ``WorkerMetrics`` (or None): each call is booked there
+        self._metrics = metrics
+        # host seconds of the backend call of the last triage that made one
+        self.call_seconds = 0.0
 
     @staticmethod
     def _screen_entry(entry, ctx) -> Optional[Tuple[int, bool]]:
@@ -229,8 +236,10 @@ class VectorJoinPlane:
                 ctx["results"] = results
             total += row[3]
         stats.activations += total
-        self.calls += 1
-        self.events += int(lens.sum())
+        call = self._last_call
+        self.call_seconds = call.seconds
+        if self._metrics is not None:
+            self._metrics.join_call(total, n_rows, call.seconds, call.compiled)
 
         if len(claimed) == len(by_subject):
             # Fully claimed: nothing materializes even on the columnar path.

@@ -50,7 +50,7 @@ import os
 import threading
 import time
 import traceback
-from typing import Any, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from .actions import (ACTIONS, BATCHED_ACTIONS, batchable_action, run_action,
                       run_condition)
@@ -59,6 +59,7 @@ from .conditions import BATCHED_CONDITIONS, CONDITIONS, FIRE_RUN_CONDITIONS
 from .context import TriggerContext
 from .events import CloudEvent
 from ..obs.trace import inject as _trace_inject
+from ..obs.trace import span as profiler_span
 from .eventstore import EventStore
 from .functions import FunctionBackend
 from .policy import (ActionTimeout, AUTO_REDRIVE_REASONS, RETRY_STATE_KEY,
@@ -237,6 +238,9 @@ class TFWorker:
         # (trace_id, span_id, span) of the fire currently running its
         # action — sink()/sink_batch() stamp it onto produced events.
         self._trace_ctx: Optional[tuple] = None
+        # (time.time(), perf_counter) of the current batch's consume: what
+        # a fire's bus wait ends at and its batch delay starts from
+        self._consumed_at: Tuple[float, float] = (0.0, 0.0)
         self.finished = False
         self.result: Any = None
         self._stop = threading.Event()
@@ -282,7 +286,8 @@ class TFWorker:
             if mode != "off":
                 from .batch import VectorJoinPlane
 
-                self._vector_plane = VectorJoinPlane(backend=mode)
+                self._vector_plane = VectorJoinPlane(backend=mode,
+                                                     metrics=self._metrics)
                 self.join_backend = self._vector_plane.backend
 
         self._recover()
@@ -416,10 +421,6 @@ class TFWorker:
                 if self._metrics is not None else empty_snapshot())
         fold_counters(snap, {f"tf_{k}_total": v
                              for k, v in self.stats.snapshot().items()})
-        plane = self._vector_plane
-        if plane is not None:
-            fold_counters(snap, {"tf_join_calls_total": plane.calls,
-                                 "tf_join_events_total": plane.events})
         snap["gauges"][f"tf_join_backend_{self.join_backend}"] = 1
         return snap
 
@@ -432,10 +433,11 @@ class TFWorker:
 
     # -- partition-aware store access --------------------------------------------
     def _consume(self, max_events: int) -> List[CloudEvent]:
-        if self.partitions is not None:
-            return self.event_store.consume_partitions(
-                self.workflow, self.partitions, max_events)
-        return self.event_store.consume(self.workflow, max_events)
+        with profiler_span("tf.consume"):
+            if self.partitions is not None:
+                return self.event_store.consume_partitions(
+                    self.workflow, self.partitions, max_events)
+            return self.event_store.consume(self.workflow, max_events)
 
     def _commit(self, event_ids: List[str]) -> None:
         if self.partitions is not None:
@@ -638,6 +640,7 @@ class TFWorker:
         cspec = entry.cspec
         bfn = entry.bfn
         stats = self.stats
+        m = self._metrics
         fired_any = False
         changed_at: Optional[int] = None
         ver = self._struct_version
@@ -712,8 +715,11 @@ class TFWorker:
                                             self.workflow, 1)
                     if span is not None:
                         self._trace_ctx = (span["trace"], span["span"], span)
+                if m is not None:
+                    self._observe_fire_lag(m, (event,), time.perf_counter())
                 try:
-                    fired = self._run_action_guarded(entry, event)
+                    with profiler_span("tf.fire"):
+                        fired = self._run_action_guarded(entry, event)
                 finally:
                     if span is not None:
                         tracer.end(span)
@@ -787,9 +793,12 @@ class TFWorker:
                     self._trace_ctx = (span["trace"], span["span"], span)
             m = self._metrics
             t_fire = time.perf_counter() if m is not None else 0.0
+            if m is not None:
+                self._observe_fire_lag(m, fired, t_fire)
             n_fired = len(fires)
             try:
-                entry.bafn(ctx, fired, entry.aspec)
+                with profiler_span("tf.fire"):
+                    entry.bafn(ctx, fired, entry.aspec)
             except Exception:  # noqa: BLE001
                 traceback.print_exc()
                 if entry.policy is not None:
@@ -813,6 +822,18 @@ class TFWorker:
             return n - 1, n_fired > 0, changed_at
         finally:
             self._slice_pos = None
+
+    def _observe_fire_lag(self, m, fired: Sequence[CloudEvent],
+                          t_start: float) -> None:
+        """Book a fire run's events: each one's wait on the bus (publish
+        stamp to its batch's consume) and the batch's delay before the run
+        (consume to ``t_start``, which covers any triage and compile that
+        ran first).  Clock reads: none here; one pair per batch."""
+        wall, mono = self._consumed_at
+        n = len(fired)
+        m.fire_wait.observe_batch(n, sum(
+            max(0.0, wall - e.time) for e in fired if e.time is not None))
+        m.fire_delay.observe_batch(n, n * (t_start - mono))
 
     def _process_group(self, subject: str, etype: str, events: List[CloudEvent],
                        processed_ids: List[str]) -> bool:
@@ -942,13 +963,15 @@ class TFWorker:
             if not batch and not self._sink:
                 return 0
             m = self._metrics
-            if m is not None and batch:
+            if m is not None:
+                now = time.time()
+                self._consumed_at = (now, time.perf_counter())
                 # publish→consume lag at batch granularity: the oldest
                 # event's publish stamp bounds every event in the batch
-                t_pub = batch[0].time
+                t_pub = batch[0].time if batch else None
                 if t_pub is not None:
                     m.consume_lag.observe_batch(
-                        len(batch), max(0.0, time.time() - t_pub) * len(batch))
+                        len(batch), max(0.0, now - t_pub) * len(batch))
             # Stores that only ever hand out uncommitted events
             # (``UNCOMMITTED_ONLY``) make the per-event committed round-trip a
             # provable no-op; in-flight dedup against ``_seen`` suffices.
@@ -974,7 +997,9 @@ class TFWorker:
                     and self._has_join_triggers()):
                 t_join = time.perf_counter() if m is not None else 0.0
                 try:
-                    res = vector_plane.triage(batch, self._entries_for, stats)
+                    with profiler_span("tf.join.triage"):
+                        res = vector_plane.triage(batch, self._entries_for,
+                                                  stats)
                 except Exception:  # noqa: BLE001
                     # e.g. a non-numeric ctx["expected"] set via introspection:
                     # screening raises before any context is mutated, so the
@@ -985,8 +1010,12 @@ class TFWorker:
                 if res is not None:
                     handled_ids, batch = res
                     if m is not None and handled_ids:
-                        m.join_kernel.observe_batch(
-                            len(handled_ids), time.perf_counter() - t_join)
+                        # a triage that returns events made one backend call
+                        t_triage = time.perf_counter() - t_join
+                        m.join_kernel.observe_batch(len(handled_ids), t_triage)
+                        m.join_host.observe_batch(
+                            len(handled_ids),
+                            t_triage - vector_plane.call_seconds)
                     n_new += len(handled_ids)
                     processed_ids.extend(handled_ids)
                     # protect the uncommitted window: even under every_batch
@@ -997,41 +1026,42 @@ class TFWorker:
             queue = batch
             qi = 0
             t_eval = time.perf_counter() if m is not None else 0.0
-            while qi < len(queue):
-                # Group the segment into type-uniform *runs* per subject:
-                # consecutive same-type events of one subject share a slice,
-                # and a type change (e.g. a timeout between result events)
-                # starts a new group — so same-subject arrival order is fully
-                # preserved across types (the bus's per-key guarantee).
-                groups: List[Tuple[str, str, List[CloudEvent]]] = []
-                current: Dict[str, List] = {}  # subject -> [type, events]
+            with profiler_span("tf.eval"):
                 while qi < len(queue):
-                    event = queue[qi]
-                    qi += 1
-                    eid = event.id
-                    if eid in seen or (
-                        is_committed is not None and is_committed(workflow, eid)
-                    ):
-                        continue  # at-least-once dedup (§3.4)
-                    seen_add(eid)
-                    if event_log is not None:
-                        event_log.append(event)
-                    n_new += 1
-                    subject = event.subject
-                    cur = current.get(subject)
-                    if cur is not None and cur[0] == event.type:
-                        cur[1].append(event)
-                    else:
-                        evs = [event]
-                        current[subject] = [event.type, evs]
-                        groups.append((subject, event.type, evs))
-                for subject, etype, evs in groups:
-                    if self._process_group(subject, etype, evs, processed_ids):
-                        fired_any = True
-                    # Drain internally-produced events in the same batch (§5.2).
-                    if self._sink:
-                        queue.extend(self._own_sink_events())
-                        self._sink.clear()
+                    # Group the segment into type-uniform *runs* per subject:
+                    # consecutive same-type events of one subject share a slice,
+                    # and a type change (e.g. a timeout between result events)
+                    # starts a new group — so same-subject arrival order is fully
+                    # preserved across types (the bus's per-key guarantee).
+                    groups: List[Tuple[str, str, List[CloudEvent]]] = []
+                    current: Dict[str, List] = {}  # subject -> [type, events]
+                    while qi < len(queue):
+                        event = queue[qi]
+                        qi += 1
+                        eid = event.id
+                        if eid in seen or (
+                            is_committed is not None and is_committed(workflow, eid)
+                        ):
+                            continue  # at-least-once dedup (§3.4)
+                        seen_add(eid)
+                        if event_log is not None:
+                            event_log.append(event)
+                        n_new += 1
+                        subject = event.subject
+                        cur = current.get(subject)
+                        if cur is not None and cur[0] == event.type:
+                            cur[1].append(event)
+                        else:
+                            evs = [event]
+                            current[subject] = [event.type, evs]
+                            groups.append((subject, event.type, evs))
+                    for subject, etype, evs in groups:
+                        if self._process_group(subject, etype, evs, processed_ids):
+                            fired_any = True
+                        # Drain internally-produced events in the same batch (§5.2).
+                        if self._sink:
+                            queue.extend(self._own_sink_events())
+                            self._sink.clear()
             stats.events_processed += n_new
             stats.batches += 1
             if m is not None and n_new:
@@ -1050,12 +1080,13 @@ class TFWorker:
             # attempts would reset the budget.
             if (fired_any or self._policy_dirty
                     or (self.commit_policy == "every_batch" and processed_ids)):
-                if m is None:
-                    self._checkpoint(processed_ids)
-                else:
-                    t_ck = time.perf_counter()
-                    self._checkpoint(processed_ids)
-                    m.checkpoint.observe(time.perf_counter() - t_ck)
+                with profiler_span("tf.checkpoint"):
+                    if m is None:
+                        self._checkpoint(processed_ids)
+                    else:
+                        t_ck = time.perf_counter()
+                        self._checkpoint(processed_ids)
+                        m.checkpoint.observe(time.perf_counter() - t_ck)
                 self._policy_dirty = False
                 if fired_any and self._dlq_size():
                     # fire progress may unblock out-of-order sequences:

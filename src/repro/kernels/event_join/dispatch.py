@@ -18,13 +18,26 @@ JAX device is a TPU, numpy otherwise.  Resolving ``auto`` imports JAX and,
 unless ``JAX_PLATFORMS`` already excludes the TPU, initializes its backend:
 on a TPU host the resolving process takes the chip, so a process that must
 stay off it asks for ``numpy`` by name.
+
+Every call is timed here, around the backend call, and left in
+``LAST_CALL`` (one record per thread) for the caller to book: its host
+seconds, and whether it compiled.  A ``jax`` or ``pallas`` backend
+registers one listener on JAX's compile-duration events; the events fire
+on the compiling thread, so a call compiled iff its own thread saw one
+during it, however many shard threads share the process.  Armed, the call
+is marked on the profiler's clock as ``tf.join.compile`` (an (N, T) shape
+this process has not dispatched before) or ``tf.join.call``.
 """
 from __future__ import annotations
 
 import os
+import threading
+import time
 from typing import Callable, Optional, Tuple
 
 import numpy as np
+
+from ...obs.trace import span
 
 JoinFn = Callable[[np.ndarray, np.ndarray, np.ndarray],
                   Tuple[np.ndarray, np.ndarray]]
@@ -38,6 +51,51 @@ def _numpy_join(events: np.ndarray, counts: np.ndarray,
     return new_counts, (new_counts >= expected).astype(np.int32)
 
 
+#: JAX's own compile phases (``jax/_src/dispatch.py``): trace, lowering,
+#: backend compile (a persistent-cache hit reports under the last).
+_COMPILE_EVENTS = frozenset((
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+))
+
+
+class _CallRecord(threading.local):
+    """Per thread: the last join call's host seconds and whether it
+    compiled, and the compile events this thread has seen."""
+
+    seconds = 0.0
+    compiled = False
+    compile_events = 0
+
+
+LAST_CALL = _CallRecord()
+_listening = False
+_dispatched: set = set()  # (fn, N, T) shapes dispatched, to name the span
+
+
+def _on_duration(event: str, duration: float, **_kw) -> None:
+    if event in _COMPILE_EVENTS:
+        LAST_CALL.compile_events += 1
+
+
+def _listen_for_compiles() -> None:
+    """Register the compile listener, once per process (a second one, from
+    a race, would count each event twice: a call still compiled or not)."""
+    global _listening
+    if not _listening:
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
+
+def _compiling(run: JoinFn) -> JoinFn:
+    run.compiles = True  # marks a backend whose new shapes compile
+    _listen_for_compiles()
+    return run
+
+
 def _make_jax_join() -> JoinFn:
     import jax
 
@@ -49,7 +107,7 @@ def _make_jax_join() -> JoinFn:
         nc, fired = f(events, counts, expected)
         return np.asarray(nc), np.asarray(fired)
 
-    return run
+    return _compiling(run)
 
 
 def _make_pallas_join() -> JoinFn:
@@ -59,7 +117,7 @@ def _make_pallas_join() -> JoinFn:
         nc, fired = event_join(events, counts, expected)
         return np.asarray(nc), np.asarray(fired)
 
-    return run
+    return _compiling(run)
 
 
 def _on_tpu() -> bool:
@@ -123,4 +181,24 @@ def join_counts_segments(lens, counts: np.ndarray, expected: np.ndarray,
             raise RuntimeError(
                 "join backend disabled (TRIGGERFLOW_JOIN_BACKEND=off)")
     event_rows = np.repeat(np.arange(len(lens), dtype=np.int32), lens)
-    return fn(event_rows, counts, expected)
+    return _timed(fn, event_rows, counts, expected)
+
+
+def _timed(fn: JoinFn, events: np.ndarray, counts: np.ndarray,
+           expected: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """``fn``'s result; its host seconds and whether it compiled are left
+    in ``LAST_CALL``."""
+    rec = LAST_CALL
+    seen = rec.compile_events
+    name = "tf.join.call"
+    if getattr(fn, "compiles", False):
+        shape = (fn, events.shape[0], counts.shape[0])
+        if shape not in _dispatched:
+            _dispatched.add(shape)
+            name = "tf.join.compile"
+    t0 = time.perf_counter()
+    with span(name):
+        out = fn(events, counts, expected)
+    rec.seconds = time.perf_counter() - t0
+    rec.compiled = rec.compile_events != seen
+    return out

@@ -17,10 +17,12 @@ from .trace import (
     EXT_KEY,
     SpanCollector,
     Tracer,
+    arm_profiler_spans,
     context_of_span,
     inject,
     load_spans,
     render_tree,
+    span,
     span_trees,
     stitch_spans,
     trace_context,
@@ -28,8 +30,9 @@ from .trace import (
 
 __all__ = [
     "Counter", "EXT_KEY", "Gauge", "Histogram", "MetricsRegistry",
-    "SpanCollector", "Tracer", "WorkerMetrics", "context_of_span",
-    "dump_metrics", "empty_snapshot", "fold_counters", "inject",
-    "load_spans", "merge_snapshot", "render_json", "render_prometheus",
-    "render_tree", "span_trees", "stitch_spans", "trace_context",
+    "SpanCollector", "Tracer", "WorkerMetrics", "arm_profiler_spans",
+    "context_of_span", "dump_metrics", "empty_snapshot", "fold_counters",
+    "inject", "load_spans", "merge_snapshot", "render_json",
+    "render_prometheus", "render_tree", "span", "span_trees",
+    "stitch_spans", "trace_context",
 ]

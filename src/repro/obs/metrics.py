@@ -204,18 +204,48 @@ def dump_metrics(snap: Mapping, prefix: str) -> List[str]:
 
 
 class WorkerMetrics:
-    """The worker's stage-boundary histograms, pre-bound so the hot loop
-    pays attribute loads, not registry dict lookups.  One instance per
-    ``TFWorker`` (= per shard)."""
+    """The worker's stage-boundary histograms and the join plane's counters,
+    pre-bound so the hot loop pays attribute loads, not registry dict
+    lookups.  One instance per ``TFWorker`` (= per shard).
+
+    The join call's parts add up: ``tf_join_kernel_seconds`` (all of
+    triage) = ``tf_join_host_seconds`` + ``tf_join_compile_seconds`` +
+    ``tf_join_roundtrip_seconds``, each call landing in exactly one of the
+    last two."""
 
     __slots__ = ("registry", "consume_lag", "batch_eval", "join_kernel",
-                 "fire", "checkpoint", "publish")
+                 "join_host", "join_compile", "join_roundtrip", "join_calls",
+                 "join_events", "join_rows", "join_compiles", "fire",
+                 "fire_wait", "fire_delay", "checkpoint", "publish")
 
     def __init__(self, registry: Optional[MetricsRegistry] = None) -> None:
         r = self.registry = registry if registry is not None else MetricsRegistry()
         self.consume_lag = r.histogram("tf_consume_lag_seconds")
         self.batch_eval = r.histogram("tf_batch_eval_seconds")
         self.join_kernel = r.histogram("tf_join_kernel_seconds")
+        self.join_host = r.histogram("tf_join_host_seconds")
+        self.join_compile = r.histogram("tf_join_compile_seconds")
+        self.join_roundtrip = r.histogram("tf_join_roundtrip_seconds")
+        self.join_calls = r.counter("tf_join_calls_total")
+        self.join_events = r.counter("tf_join_events_total")
+        self.join_rows = r.counter("tf_join_rows_total")
+        self.join_compiles = r.counter("tf_join_compiles_total")
         self.fire = r.histogram("tf_fire_seconds")
+        self.fire_wait = r.histogram("tf_fire_wait_seconds")
+        self.fire_delay = r.histogram("tf_fire_delay_seconds")
         self.checkpoint = r.histogram("tf_checkpoint_seconds")
         self.publish = r.histogram("tf_publish_seconds")
+
+    def join_call(self, events: int, rows: int, seconds: float,
+                  compiled: bool) -> None:
+        """One call into the join backend: ``events`` (N) folded into
+        ``rows`` (T) trigger rows, ``seconds`` around the backend call, which
+        either compiled a kernel shape or was a plain round trip."""
+        self.join_calls.inc()
+        self.join_events.inc(events)
+        self.join_rows.inc(rows)
+        if compiled:
+            self.join_compiles.inc()
+            self.join_compile.observe(seconds)
+        else:
+            self.join_roundtrip.observe(seconds)

@@ -24,9 +24,18 @@ Sampling: the decision is made once, at the root.  A traced event is
 always followed (context propagation is never sampled away mid-chain);
 an *untraced* fire starts a new trace only when the tracer's sampler
 admits it.  ``sample=1.0`` is full tracing, ``0.0`` is propagate-only.
+
+Beside the causal plane, ``span(name)`` marks where the hot path spends
+its time on the profiler's clock: armed (``arm_profiler_spans(True)``) it
+opens a ``jax.profiler.TraceAnnotation``, the TraceMe that the device
+trace is written with, so a host span and the device's idle gaps share
+one clock.  Disarmed (the default) it returns one shared no-op context
+and imports nothing: process-mode shards join on the numpy path and
+never touch JAX.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import time
@@ -39,6 +48,30 @@ if TYPE_CHECKING:  # annotation-only: keeps obs free of core imports (no cycle)
 
 #: CloudEvents extension attribute carrying ``[trace_id, span_id]``.
 EXT_KEY = "tftrace"
+
+_NO_SPAN = contextlib.nullcontext()
+_annotation = None  # jax.profiler.TraceAnnotation while armed, else None
+
+
+def arm_profiler_spans(on: bool = True) -> None:
+    """Make ``span()`` open profiler annotations (``on``) or no-ops.  Only
+    a process that traces its own device arms them; the first arming
+    imports JAX."""
+    global _annotation
+    if on:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    else:
+        _annotation = None
+
+
+def span(name: str):
+    """A context that marks ``name`` on the profiler's clock while armed.
+    Names are stable and carry no per-call data (``tf.<stage>``)."""
+    if _annotation is None:
+        return _NO_SPAN
+    return _annotation(name)
 
 
 def new_id() -> str:

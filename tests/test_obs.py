@@ -13,9 +13,15 @@ Covers:
 * end-to-end trace propagation: a fan-out DAG driven through
   ``ctx.produce_batch`` yields ONE connected span tree per run on the
   thread pool, the process pool, and across a real SIGKILL crash/replay
-  (open span records + span-id dedup at stitch time).
+  (open span records + span-id dedup at stitch time),
+* the join call's books (compile, round trip, host against all of
+  triage, on the ``jax`` backend standing in for ``pallas``), fire wait
+  and delay, and the profiler spans' disarmed default.
 """
 import os
+import subprocess
+import sys
+import threading
 import time
 
 import pytest
@@ -25,8 +31,9 @@ from repro.core import Triggerflow, make_trigger, termination_event
 from repro.obs.metrics import (DEFAULT_BOUNDS, Histogram, MetricsRegistry,
                                dump_metrics, empty_snapshot, fold_counters,
                                merge_snapshot, render_prometheus)
-from repro.obs.trace import (Tracer, context_of_span, inject, span_trees,
-                             stitch_spans, trace_context)
+from repro.obs.trace import (Tracer, arm_profiler_spans, context_of_span,
+                             inject, span, span_trees, stitch_spans,
+                             trace_context)
 
 
 # -- metrics registry ------------------------------------------------------------
@@ -376,3 +383,176 @@ def test_trace_report_cli(tmp_path):
         capture_output=True, text=True, env=env)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "disconnected" in out.stdout  # the "0 disconnected" summary line
+
+
+# -- the join call's books and the fire path -------------------------------------
+
+def _join_worker(n_triggers, expected=10 ** 6, vector_join="jax",
+                 action_plane=True, reset_on_fire=False):
+    from repro.core import MemoryEventStore, MemoryStateStore
+    from repro.core.functions import FunctionBackend
+    from repro.core.worker import TFWorker
+
+    es = MemoryEventStore()
+    w = TFWorker("w", es, MemoryStateStore(), FunctionBackend(es, inline=True),
+                 commit_policy="every_batch", keep_event_log=False,
+                 vector_join=vector_join, action_plane=action_plane)
+    for t in range(n_triggers):
+        w.add_trigger(make_trigger(
+            f"s{t}", condition={"name": "counter", "expected": expected,
+                                "reset_on_fire": reset_on_fire},
+            action={"name": "noop"}, trigger_id=f"j{t}", transient=False))
+    return w
+
+
+def _publish_round(w, n_events, n_triggers):
+    w.event_store.publish_batch("w", [
+        termination_event(f"s{i % n_triggers}", i) for i in range(n_events)])
+
+
+def _join_books(snap):
+    h, c = snap["histograms"], snap["counters"]
+    return {"kernel": h["tf_join_kernel_seconds"]["sum"],
+            "parts": (h["tf_join_host_seconds"]["sum"]
+                      + h["tf_join_compile_seconds"]["sum"]
+                      + h["tf_join_roundtrip_seconds"]["sum"]),
+            "compiles": c["tf_join_compiles_total"],
+            "compile_calls": h["tf_join_compile_seconds"]["count"],
+            "compile_s": h["tf_join_compile_seconds"]["sum"],
+            "roundtrips": h["tf_join_roundtrip_seconds"]["count"],
+            "calls": c["tf_join_calls_total"],
+            "events": c["tf_join_events_total"],
+            "rows": c["tf_join_rows_total"]}
+
+
+def test_join_call_books_compile_then_round_trip():
+    """A new (N, T) shape compiles once, a repeat is a round trip; host +
+    compile + round trip is all of triage; rows sum T, events sum N."""
+    pytest.importorskip("jax")
+    n, t = 1013, 11  # a shape no other test dispatches
+    w = _join_worker(t)
+    _publish_round(w, n, t)
+    assert w.run_once(n) == n
+    first = _join_books(w.metrics_snapshot())
+    assert first["compiles"] == 1 and first["compile_calls"] == 1
+    assert first["compile_s"] > 0 and first["roundtrips"] == 0
+    _publish_round(w, n, t)
+    assert w.run_once(n) == n
+    books = _join_books(w.metrics_snapshot())
+    assert books["compiles"] == 1 and books["compile_calls"] == 1
+    assert books["roundtrips"] == 1
+    assert books["parts"] == pytest.approx(books["kernel"], rel=0.01)
+    assert books["calls"] == 2
+    assert books["events"] == 2 * n and books["rows"] == 2 * t
+
+
+def test_join_compiles_charged_to_the_compiling_thread():
+    """Two shard threads, each compiling its own shape at the same time:
+    each compile lands in the registry of the thread that paid for it."""
+    pytest.importorskip("jax")
+    shapes = [(1031, 13), (1039, 17)]  # shapes no other test dispatches
+    workers = [_join_worker(t) for _, t in shapes]
+    for w, (n, t) in zip(workers, shapes):
+        _publish_round(w, n, t)
+    start = threading.Barrier(len(workers))
+    done = []
+
+    def run(w, n):
+        start.wait(timeout=30)
+        done.append(w.run_once(n))
+
+    threads = [threading.Thread(target=run, args=(w, n))
+               for w, (n, _) in zip(workers, shapes)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=120)
+        assert not th.is_alive()
+    assert sorted(done) == sorted(n for n, _ in shapes)
+    for w, (n, t) in zip(workers, shapes):
+        books = _join_books(w.metrics_snapshot())
+        assert books["compiles"] == 1 and books["roundtrips"] == 0
+        assert books["compile_s"] > 0
+        assert (books["events"], books["rows"]) == (n, t)
+
+
+@pytest.mark.parametrize("action_plane", [True, False])
+def test_fire_wait_and_delay_count_the_fired_events(action_plane):
+    """Over a small join with ``reset_on_fire``, every fired event is booked
+    once in each of fire wait and fire delay, on the fire-run path and on
+    its per-fire twin."""
+    t, expected, rounds = 4, 5, 3
+    w = _join_worker(t, expected=expected, vector_join="numpy",
+                     action_plane=action_plane, reset_on_fire=True)
+    _publish_round(w, t * expected * rounds, t)
+    while w.run_once(7):
+        pass
+    snap = w.metrics_snapshot()
+    fires = snap["counters"]["tf_fires_total"]
+    assert fires == t * rounds
+    h = snap["histograms"]
+    assert h["tf_fire_wait_seconds"]["count"] == fires
+    assert h["tf_fire_delay_seconds"]["count"] == fires
+    assert 0 <= h["tf_fire_wait_seconds"]["sum"] < 60
+    assert 0 <= h["tf_fire_delay_seconds"]["sum"] < 60
+
+
+def test_profiler_spans_disarmed_by_default_and_free_of_jax():
+    assert span("tf.a") is span("tf.b")
+    with span("tf.a"):
+        pass
+    code = ("import sys, repro.obs, repro.core; "
+            "from repro.obs import span; "
+            "assert span('tf.x') is span('tf.y'); "
+            "assert 'jax' not in sys.modules, 'jax imported'")
+    env = dict(os.environ)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = os.path.join(repo, "src")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+def test_profiler_spans_armed_open_annotations():
+    jax = pytest.importorskip("jax")
+    arm_profiler_spans(True)
+    try:
+        s = span("tf.consume")
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+        with s:
+            pass
+    finally:
+        arm_profiler_spans(False)
+    assert span("tf.a") is span("tf.b")
+
+
+def _span_probe(ctx, event, params):
+    with open(params["path"], "a", encoding="utf-8") as f:
+        f.write("%s\n" % (span("tf.a") is span("tf.b")))
+
+
+def test_process_shards_never_arm_profiler_spans(tmp_path):
+    """A shard process forked from a parent that armed its spans runs
+    disarmed: it owns no device and must never open a profiler
+    annotation."""
+    pytest.importorskip("jax")
+    from repro.core import register_action
+
+    register_action("obs_test.span_probe", _span_probe)
+    marker = tmp_path / "probe.txt"
+    arm_profiler_spans(True)
+    try:
+        pool = ProcessShardPool(str(tmp_path / "pool"), num_partitions=2,
+                                batch_size=16)
+        pool.create_workflow("w")
+        pool.add_trigger("w", make_trigger(
+            "s", condition={"name": "true"},
+            action={"name": "obs_test.span_probe", "path": str(marker)},
+            trigger_id="t", transient=False))
+        pool.publish_batch("w", [termination_event("s", i) for i in range(4)])
+        pool.start_shards("w", 1)
+        pool.wait_drained("w", timeout=60)
+        pool.stop_all()
+    finally:
+        arm_profiler_spans(False)
+    assert marker.read_text().split() == ["True"] * 4
