@@ -239,7 +239,8 @@ class VectorJoinPlane:
         call = self._last_call
         self.call_seconds = call.seconds
         if self._metrics is not None:
-            self._metrics.join_call(total, n_rows, call.seconds, call.compiled)
+            self._metrics.join_call(total, n_rows, call.seconds, call.compiled,
+                                    call.pad_events, call.pad_rows)
 
         if len(claimed) == len(by_subject):
             # Fully claimed: nothing materializes even on the columnar path.

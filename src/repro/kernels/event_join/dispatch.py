@@ -25,8 +25,16 @@ seconds, and whether it compiled.  A ``jax`` or ``pallas`` backend
 registers one listener on JAX's compile-duration events; the events fire
 on the compiling thread, so a call compiled iff its own thread saw one
 during it, however many shard threads share the process.  Armed, the call
-is marked on the profiler's clock as ``tf.join.compile`` (an (N, T) shape
+is marked on the profiler's clock as ``tf.join.compile`` (a bucketed shape
 this process has not dispatched before) or ``tf.join.call``.
+
+A backend that compiles one program per shape (``jax``, ``pallas``) is
+called on bucketed shapes: N and T are padded up to a power of two, at
+least ``EVENTS_BUCKET`` events and ``ROWS_BUCKET`` rows, so the batches of
+a running deployment share a handful of programs instead of compiling one
+per (N, T).  Padded events are −1, padded rows count 0 against an
+unreachable threshold, and the outputs are cut back to T rows; the padding
+a call carried is left in ``LAST_CALL``.  ``numpy`` is called exact.
 """
 from __future__ import annotations
 
@@ -60,18 +68,34 @@ _COMPILE_EVENTS = frozenset((
 ))
 
 
+#: Least bucket of events: the default consume cap, so every batch of a
+#: default-size consume shares one program.
+EVENTS_BUCKET = 512
+#: Least bucket of trigger rows: one lane row, so T stays lane-aligned.
+ROWS_BUCKET = 128
+_NEVER = np.iinfo(np.int32).max  # a padded row's threshold
+
+
+def _bucket(n: int, least: int) -> int:
+    """``max(least, next power of two >= n)``."""
+    return max(least, 1 << (n - 1).bit_length())
+
+
 class _CallRecord(threading.local):
-    """Per thread: the last join call's host seconds and whether it
-    compiled, and the compile events this thread has seen."""
+    """Per thread: the last join call's host seconds, whether it compiled
+    and the padding it carried, and the compile events this thread has
+    seen."""
 
     seconds = 0.0
     compiled = False
+    pad_events = 0
+    pad_rows = 0
     compile_events = 0
 
 
 LAST_CALL = _CallRecord()
 _listening = False
-_dispatched: set = set()  # (fn, N, T) shapes dispatched, to name the span
+_dispatched: set = set()  # (fn, N, T) bucketed shapes, to name the span
 
 
 def _on_duration(event: str, duration: float, **_kw) -> None:
@@ -174,14 +198,28 @@ def join_counts_segments(lens, counts: np.ndarray, expected: np.ndarray,
     to trigger row ``i``.  This is the shape the columnar ingest path
     produces (a batch bucketed by subject is runs of row ids, never a
     ragged scatter), so the row-id expansion lives here next to the kernel
-    instead of in every caller."""
+    instead of in every caller.
+
+    On a backend that compiles per shape the call is padded to its bucket
+    (module docstring); the caller sees T rows either way."""
     if fn is None:
         _name, fn = resolve_join_backend()
         if fn is None:
             raise RuntimeError(
                 "join backend disabled (TRIGGERFLOW_JOIN_BACKEND=off)")
-    event_rows = np.repeat(np.arange(len(lens), dtype=np.int32), lens)
-    return _timed(fn, event_rows, counts, expected)
+    n, t = int(np.sum(lens)), counts.shape[0]
+    n_pad, t_pad = n, t
+    if getattr(fn, "compiles", False):
+        n_pad, t_pad = _bucket(n, EVENTS_BUCKET), _bucket(t, ROWS_BUCKET)
+    events = np.full(n_pad, -1, np.int32)
+    events[:n] = np.repeat(np.arange(t, dtype=np.int32), lens)
+    if t_pad > t:
+        counts = np.concatenate([counts, np.zeros(t_pad - t, np.int32)])
+        expected = np.concatenate(
+            [expected, np.full(t_pad - t, _NEVER, np.int32)])
+    new_counts, fired = _timed(fn, events, counts, expected)
+    LAST_CALL.pad_events, LAST_CALL.pad_rows = n_pad - n, t_pad - t
+    return new_counts[:t], fired[:t]
 
 
 def _timed(fn: JoinFn, events: np.ndarray, counts: np.ndarray,

@@ -215,7 +215,8 @@ class WorkerMetrics:
 
     __slots__ = ("registry", "consume_lag", "batch_eval", "join_kernel",
                  "join_host", "join_compile", "join_roundtrip", "join_calls",
-                 "join_events", "join_rows", "join_compiles", "fire",
+                 "join_events", "join_rows", "join_compiles",
+                 "join_pad_events", "join_pad_rows", "fire",
                  "fire_wait", "fire_delay", "checkpoint", "publish")
 
     def __init__(self, registry: Optional[MetricsRegistry] = None) -> None:
@@ -230,6 +231,8 @@ class WorkerMetrics:
         self.join_events = r.counter("tf_join_events_total")
         self.join_rows = r.counter("tf_join_rows_total")
         self.join_compiles = r.counter("tf_join_compiles_total")
+        self.join_pad_events = r.counter("tf_join_pad_events_total")
+        self.join_pad_rows = r.counter("tf_join_pad_rows_total")
         self.fire = r.histogram("tf_fire_seconds")
         self.fire_wait = r.histogram("tf_fire_wait_seconds")
         self.fire_delay = r.histogram("tf_fire_delay_seconds")
@@ -237,13 +240,17 @@ class WorkerMetrics:
         self.publish = r.histogram("tf_publish_seconds")
 
     def join_call(self, events: int, rows: int, seconds: float,
-                  compiled: bool) -> None:
+                  compiled: bool, pad_events: int, pad_rows: int) -> None:
         """One call into the join backend: ``events`` (N) folded into
-        ``rows`` (T) trigger rows, ``seconds`` around the backend call, which
-        either compiled a kernel shape or was a plain round trip."""
+        ``rows`` (T) trigger rows, padded by ``pad_events`` and ``pad_rows``
+        up to the shape bucket the backend ran, ``seconds`` around the
+        backend call, which either compiled a kernel shape or was a plain
+        round trip."""
         self.join_calls.inc()
         self.join_events.inc(events)
         self.join_rows.inc(rows)
+        self.join_pad_events.inc(pad_events)
+        self.join_pad_rows.inc(pad_rows)
         if compiled:
             self.join_compiles.inc()
             self.join_compile.observe(seconds)

@@ -498,6 +498,38 @@ def test_join_backends_agree():
     assert (nc_np == nc_jx).all() and (f_np == f_jx).all()
 
 
+@pytest.mark.parametrize("t", [1, 127, 128, 129])
+@pytest.mark.parametrize("n", [1, 511, 512, 513, 1024, 1025])
+def test_bucketed_jax_join_matches_exact_numpy(n, t):
+    """The ``jax`` backend, padded to its shape bucket, returns what exact
+    ``numpy`` returns for the T real rows: the row brought to its threshold
+    fires, the rows one event short do not, and no padded row shows."""
+    np = pytest.importorskip("numpy")
+    pytest.importorskip("jax")
+    from repro.kernels.event_join.dispatch import (LAST_CALL,
+                                                   join_counts_segments,
+                                                   resolve_join_backend)
+
+    rng = np.random.default_rng(n * 1000 + t)
+    lens = rng.multinomial(n, np.full(t, 1.0 / t)).astype(np.int64)
+    counts = rng.integers(0, 50, t).astype(np.int32)
+    expected = (counts + lens + 1).astype(np.int32)
+    hit = int(np.argmax(lens))
+    expected[hit] -= 1  # exactly at its threshold after this call
+    _, np_fn = resolve_join_backend("numpy")
+    _, jax_fn = resolve_join_backend("jax")
+    want = join_counts_segments(lens, counts, expected, np_fn)
+    assert (LAST_CALL.pad_events, LAST_CALL.pad_rows) == (0, 0)
+    got = join_counts_segments(lens, counts, expected, jax_fn)
+    assert (LAST_CALL.pad_events, LAST_CALL.pad_rows) == (
+        max(512, 1 << (n - 1).bit_length()) - n,
+        max(128, 1 << (t - 1).bit_length()) - t)
+    assert got[0].shape == got[1].shape == (t,)
+    assert got[0].tolist() == want[0].tolist() == (counts + lens).tolist()
+    assert got[1].tolist() == want[1].tolist()
+    assert np.flatnonzero(got[1]).tolist() == [hit]
+
+
 @pytest.mark.parametrize("vector_join,backend", [(None, "numpy"),
                                                  ("off", "off")])
 def test_join_backend_is_recorded(vector_join, backend):

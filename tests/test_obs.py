@@ -429,7 +429,7 @@ def test_join_call_books_compile_then_round_trip():
     """A new (N, T) shape compiles once, a repeat is a round trip; host +
     compile + round trip is all of triage; rows sum T, events sum N."""
     pytest.importorskip("jax")
-    n, t = 1013, 11  # a shape no other test dispatches
+    n, t = 8193, 11  # bucket (16384, 128): no other test dispatches it
     w = _join_worker(t)
     _publish_round(w, n, t)
     assert w.run_once(n) == n
@@ -450,7 +450,8 @@ def test_join_compiles_charged_to_the_compiling_thread():
     """Two shard threads, each compiling its own shape at the same time:
     each compile lands in the registry of the thread that paid for it."""
     pytest.importorskip("jax")
-    shapes = [(1031, 13), (1039, 17)]  # shapes no other test dispatches
+    # buckets (4096, 128) and (8192, 128): no other test dispatches them
+    shapes = [(2049, 13), (4097, 17)]
     workers = [_join_worker(t) for _, t in shapes]
     for w, (n, t) in zip(workers, shapes):
         _publish_round(w, n, t)
@@ -474,6 +475,40 @@ def test_join_compiles_charged_to_the_compiling_thread():
         assert books["compiles"] == 1 and books["roundtrips"] == 0
         assert books["compile_s"] > 0
         assert (books["events"], books["rows"]) == (n, t)
+
+
+def test_join_shapes_in_one_bucket_compile_once():
+    """Two calls of different (N, T) that pad to one shape bucket book one
+    compile and one round trip, each with its own unpadded N and T."""
+    pytest.importorskip("jax")
+    t = 300
+    w = _join_worker(t)
+    # (600, 300) and (900, 260) share bucket (1024, 512), no other test's
+    for n, subjects in ((600, t), (900, 260)):
+        _publish_round(w, n, subjects)
+        assert w.run_once(n) == n
+    books = _join_books(w.metrics_snapshot())
+    assert books["compiles"] == 1 and books["roundtrips"] == 1
+    assert books["calls"] == 2
+    assert books["events"] == 600 + 900 and books["rows"] == t + 260
+
+
+@pytest.mark.parametrize("backend,pads", [("jax", (512 - 300, 128 - 11)),
+                                          ("numpy", (0, 0))])
+def test_join_pad_counters_book_the_bucket_padding(backend, pads):
+    """A compiling backend books ``bucket - N`` padded events and
+    ``bucket - T`` padded rows a call; ``numpy`` runs exact and books 0."""
+    if backend == "jax":
+        pytest.importorskip("jax")
+    n, t = 300, 11
+    w = _join_worker(t, vector_join=backend)
+    for rnd in (1, 2):
+        _publish_round(w, n, t)
+        assert w.run_once(n) == n
+        c = w.metrics_snapshot()["counters"]
+        assert (c["tf_join_pad_events_total"],
+                c["tf_join_pad_rows_total"]) == (rnd * pads[0], rnd * pads[1])
+        assert c["tf_join_events_total"] == rnd * n
 
 
 @pytest.mark.parametrize("action_plane", [True, False])

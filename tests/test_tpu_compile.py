@@ -54,8 +54,10 @@ def no_compile_cache():
 
 
 # (N events, T triggers): one worker batch at Table-1 width, the whole
-# Table-1 join in one call, and the sharded pool's steady per-shard batch.
-@pytest.mark.parametrize("n,t", [(4096, 100), (200_000, 100), (512, 13)])
+# Table-1 join in one call, the sharded pool's steady per-shard batch, and
+# the shape buckets join dispatch pads a default batch and a larger one to.
+@pytest.mark.parametrize("n,t", [(4096, 100), (200_000, 100), (512, 13),
+                                 (512, 128), (1024, 128)])
 def test_event_join_compiles_for_v5e(one_chip, no_compile_cache, n, t):
     from repro.kernels.event_join.ops import event_join
 
