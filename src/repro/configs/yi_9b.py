@@ -7,6 +7,7 @@ def full_config() -> ModelConfig:
         arch="yi-9b", family="dense",
         n_layers=48, d_model=4096, n_heads=32, n_kv_heads=4,
         d_ff=11008, vocab=64000, head_dim=128, rope_theta=10000.0,
+        rms_eps=1e-6,
     )
 
 
@@ -14,5 +15,5 @@ def smoke_config() -> ModelConfig:
     return ModelConfig(
         arch="yi-9b-smoke", family="dense",
         n_layers=2, d_model=64, n_heads=4, n_kv_heads=2,
-        d_ff=128, vocab=256, head_dim=16, q_chunk=32, kv_chunk=32,
+        d_ff=128, vocab=256, head_dim=16, rms_eps=1e-6, q_chunk=32, kv_chunk=32,
     )

@@ -8,7 +8,7 @@ source and the controller/autoscaler.
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, Iterable, List, Optional, Union
+from typing import Any, Callable, Dict, Iterable, List, Optional, Union
 
 from .events import TYPE_INIT, CloudEvent
 from .eventstore import EventStore, MemoryEventStore
@@ -49,6 +49,9 @@ class Triggerflow:
         self.num_shards = max(1, num_shards)
         self._workers: Dict[str, TFWorker] = {}
         self._threads: Dict[str, threading.Thread] = {}
+        # per workflow, snapshot functions of components that run inside
+        # its actions and outlive its workers (a serving engine)
+        self._metric_sources: Dict[str, List[Callable[[], Dict]]] = {}
         self._lock = threading.RLock()
         # Sharded runtime rides on any partition-capable store (repro.bus).
         self.pool = pool
@@ -203,18 +206,28 @@ class Triggerflow:
                 return self.pool.result(workflow)
         return self.worker(workflow).run_until_complete(timeout=timeout)
 
+    def add_metrics_source(self, workflow: str, snapshot: Callable[[], Dict]) -> None:
+        """Fold ``snapshot()`` into every scrape of ``workflow``."""
+        with self._lock:
+            self._metric_sources.setdefault(workflow, []).append(snapshot)
+
     def metrics_snapshot(self, workflow: Optional[str] = None) -> Dict[str, Any]:
         """One aggregated metrics snapshot for the whole deployment: every
         classic facade worker plus, when a shard pool serves the workflows,
         the pool's per-shard registries (thread pool merges in-process;
-        process pool scrapes over the command pipe)."""
+        process pool scrapes over the command pipe), plus the sources
+        added with ``add_metrics_source``."""
         from ..obs.metrics import empty_snapshot, merge_snapshot
         snap = empty_snapshot()
         with self._lock:
             workers = list(self._workers.values())
+            sources = [fn for wf, fns in self._metric_sources.items()
+                       if workflow is None or wf == workflow for fn in fns]
         for w in workers:
             if workflow is None or w.workflow == workflow:
                 merge_snapshot(snap, w.metrics_snapshot())
+        for fn in sources:
+            merge_snapshot(snap, fn())
         if self.pool is not None and hasattr(self.pool, "obs_snapshot"):
             wfs = [workflow] if workflow is not None \
                 else self.event_store.workflows()

@@ -33,11 +33,13 @@ def main() -> None:
     eng = ServingEngine(cfg, tf, "serve", max_batch=args.max_batch,
                         max_new_tokens=args.max_new_tokens, max_len=256)
     eng.deploy()
+    prompts = [[1 + i, 2 + i, 3 + i] for i in range(args.requests)]
+    eng.warmup([len(p) for p in prompts])
     scaler = KedaAutoscaler(tf, poll_interval=0.05, grace_period=0.5).start()
     t0 = time.time()
     try:
-        for i in range(args.requests):
-            eng.submit(f"req-{i}", [1 + i, 2 + i, 3 + i])
+        for i, prompt in enumerate(prompts):
+            eng.submit(f"req-{i}", prompt)
         while eng.served < args.requests and time.time() - t0 < 300:
             time.sleep(0.05)
         print(f"served {eng.served} requests in {eng.batches} batches, "

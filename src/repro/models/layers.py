@@ -44,7 +44,9 @@ def rms_norm_init(key, d, name="norm"):
     return {"w": make_param(key, (d,), ("embed",), init="ones")}
 
 
-def rms_norm(params, x, eps=1e-5):
+def rms_norm(params, x, eps):
+    """``eps`` is the model's own (``ModelConfig.rms_eps``): no default, so
+    that every call states it."""
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps)
@@ -90,9 +92,19 @@ def mrope_angles(positions3, head_dim: int, sections, theta: float = 10000.0):
 
 
 # -- attention ------------------------------------------------------------------------
-def attention_naive(q, k, v, causal=True, kv_len=None, pos_offset=0):
+def _row_mask(mask, kv_pos, kv_start):
+    """[Sq,Skv] mask -> [B|1,Sq,Skv]: with ``kv_start`` [B], row b also
+    masks the keys before its first valid slot (its left padding)."""
+    if kv_start is None:
+        return mask[None]
+    return mask[None] & (kv_pos[None, None, :] >= kv_start[:, None, None])
+
+
+def attention_naive(q, k, v, causal=True, kv_len=None, pos_offset=0,
+                    kv_start=None):
     """Reference O(S²)-memory attention (oracle for tests; never the prod path).
-    q [B,Sq,Hq,D], k/v [B,Skv,Hkv,D] with Hq = G*Hkv."""
+    q [B,Sq,Hq,D], k/v [B,Skv,Hkv,D] with Hq = G*Hkv; ``kv_start`` [B] masks
+    each row's keys before its first valid slot (left padding)."""
     B, Sq, Hq, D = q.shape
     Hkv = k.shape[2]
     G = Hq // Hkv
@@ -106,16 +118,18 @@ def attention_naive(q, k, v, causal=True, kv_len=None, pos_offset=0):
         mask &= q_pos[:, None] >= kv_pos[None, :]
     if kv_len is not None:
         mask &= kv_pos[None, :] < kv_len
-    scores = jnp.where(mask[None, None, None], scores, -1e30)
+    mask = _row_mask(mask, kv_pos, kv_start)
+    scores = jnp.where(mask[:, None, None], scores, -1e30)
     p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     out = jnp.einsum("bhgst,bthd->bshgd", p, v)
     return out.reshape(B, Sq, Hq, D)
 
 
 def attention_chunked(q, k, v, causal=True, kv_len=None, pos_offset=0,
-                      q_chunk=2048, kv_chunk=2048, unroll=False):
+                      q_chunk=2048, kv_chunk=2048, unroll=False, kv_start=None):
     """Online-softmax flash attention in pure JAX: double scan over q/kv chunks.
     Peak intermediate is [B,Hkv,G,qc,kc] — no S×S materialization.
+    ``kv_start`` [B] masks each row's left padding, as in ``attention_naive``.
 
     ``unroll=True`` emits the chunk loops as straight-line HLO (and *skips*
     fully-masked causal blocks — the triangular schedule, ~2× fewer FLOPs).
@@ -123,7 +137,7 @@ def attention_chunked(q, k, v, causal=True, kv_len=None, pos_offset=0,
     ``while`` bodies by trip count) and available as a production option."""
     if unroll:
         return _attention_unrolled(q, k, v, causal, kv_len, pos_offset,
-                                   q_chunk, kv_chunk)
+                                   q_chunk, kv_chunk, kv_start)
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     Dv = v.shape[-1]  # value head dim may differ (MLA)
@@ -157,7 +171,8 @@ def attention_chunked(q, k, v, causal=True, kv_len=None, pos_offset=0,
                 msk &= (kv_pos < kv_len)[None, :]
             else:
                 msk &= (kv_pos < Skv)[None, :]
-            s = jnp.where(msk[None, None, None], s, neg)
+            msk = _row_mask(msk, kv_pos, kv_start)
+            s = jnp.where(msk[:, None, None], s, neg)
             m_new = jnp.maximum(m_prev, s.max(-1))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new[..., None])
@@ -180,7 +195,8 @@ def attention_chunked(q, k, v, causal=True, kv_len=None, pos_offset=0,
     return out[:, :Sq]
 
 
-def _attention_unrolled(q, k, v, causal, kv_len, pos_offset, q_chunk, kv_chunk):
+def _attention_unrolled(q, k, v, causal, kv_len, pos_offset, q_chunk, kv_chunk,
+                        kv_start):
     """Straight-line flash attention with causal block skipping."""
     B, Sq, Hq, D = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
@@ -214,7 +230,8 @@ def _attention_unrolled(q, k, v, causal, kv_len, pos_offset, q_chunk, kv_chunk):
             if causal:
                 msk &= q_pos[:, None] >= kv_pos[None, :]
             msk &= (kv_pos < (Skv if kv_len is None else kv_len))[None, :]
-            s = jnp.where(msk[None, None, None], s, neg)
+            msk = _row_mask(msk, kv_pos, kv_start)
+            s = jnp.where(msk[:, None, None], s, neg)
             m_new = jnp.maximum(m, s.max(-1))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(s - m_new[..., None])
@@ -227,9 +244,10 @@ def _attention_unrolled(q, k, v, causal, kv_len, pos_offset, q_chunk, kv_chunk):
     return jnp.concatenate(outs, axis=1)[:, :Sq]
 
 
-def attention_decode(q, k_cache, v_cache, pos):
+def attention_decode(q, k_cache, v_cache, pos, start=None):
     """Single-token decode vs a (padded) cache.  q [B,1,Hq,D],
-    caches [B,T,Hkv,D], ``pos`` = number of valid cache entries (int or [B])."""
+    caches [B,T,Hkv,D], ``pos`` = number of filled cache slots (int or [B]);
+    ``start`` [B], each row's first valid slot (after its left padding)."""
     B, _, Hq, D = q.shape
     T, Hkv = k_cache.shape[1], k_cache.shape[2]
     G = Hq // Hkv
@@ -238,6 +256,8 @@ def attention_decode(q, k_cache, v_cache, pos):
     s = s / math.sqrt(D)
     kv_pos = jnp.arange(T)
     valid = kv_pos[None, :] < (pos if jnp.ndim(pos) else pos + jnp.zeros((B,), jnp.int32))[:, None]
+    if start is not None:
+        valid &= kv_pos[None, :] >= start[:, None]
     s = jnp.where(valid[:, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bhgt,bthd->bhgd", p, v_cache)
@@ -270,28 +290,29 @@ def gqa_out(params, attn):
 
 
 def gqa_forward(params, x, cos, sin, causal=True, q_chunk=2048, kv_chunk=2048,
-                return_kv=False, unroll=False):
+                return_kv=False, unroll=False, kv_start=None):
     q, k, v = gqa_qkv(params, x)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     q = lsc(q, "batch", "seq", "heads", None)
     k = lsc(k, "batch", "seq", "kv_heads", None)
     attn = attention_chunked(q, k, v, causal=causal, q_chunk=q_chunk,
-                             kv_chunk=kv_chunk, unroll=unroll)
+                             kv_chunk=kv_chunk, unroll=unroll, kv_start=kv_start)
     out = gqa_out(params, attn)
     if return_kv:
         return out, (k, v)
     return out
 
 
-def gqa_decode(params, x, cache_k, cache_v, pos, cos, sin):
-    """x [B,1,D]; writes K/V at ``pos`` and attends over the valid prefix."""
+def gqa_decode(params, x, cache_k, cache_v, pos, cos, sin, start=None):
+    """x [B,1,D]; writes K/V at ``pos`` and attends over each row's valid
+    slots ``[start, pos]``."""
     q, k, v = gqa_qkv(params, x)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
     cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), pos, 1)
     cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), pos, 1)
-    out = attention_decode(q, cache_k, cache_v, pos + 1)
+    out = attention_decode(q, cache_k, cache_v, pos + 1, start)
     return gqa_out(params, out), cache_k, cache_v
 
 

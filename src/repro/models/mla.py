@@ -35,8 +35,8 @@ def mla_init(keys: KeyGen, d_model: int, n_heads: int, q_lora: int, kv_lora: int
     return p
 
 
-def _queries(params, x, cos, sin, nope_dim):
-    cq = rms_norm(params["q_norm"], x @ params["wdq"])
+def _queries(params, x, cos, sin, nope_dim, eps):
+    cq = rms_norm(params["q_norm"], x @ params["wdq"], eps)
     q = jnp.einsum("bsq,qhk->bshk", cq, params["wuq"])
     qn, qr = q[..., :nope_dim], q[..., nope_dim:]
     qr = apply_rope(qr, cos, sin)
@@ -45,12 +45,12 @@ def _queries(params, x, cos, sin, nope_dim):
 
 def mla_forward(params, x, positions, nope_dim=128, rope_dim=64,
                 rope_theta=10000.0, q_chunk=2048, kv_chunk=2048, return_cache=False,
-                unroll=False):
+                unroll=False, *, eps):
     """Training/prefill path: expand the latent into full K/V per head."""
     B, S, D = x.shape
     cos, sin = rope_angles(positions, rope_dim, rope_theta)
-    qn, qr = _queries(params, x, cos, sin, nope_dim)
-    ckv = rms_norm(params["kv_norm"], x @ params["wdkv"])           # [B,S,kvl]
+    qn, qr = _queries(params, x, cos, sin, nope_dim, eps)
+    ckv = rms_norm(params["kv_norm"], x @ params["wdkv"], eps)      # [B,S,kvl]
     kr = apply_rope((x @ params["wkr"])[:, :, None, :], cos, sin)   # [B,S,1,rope]
     kn = jnp.einsum("bsc,chk->bshk", ckv, params["wuk"])
     v = jnp.einsum("bsc,chk->bshk", ckv, params["wuv"])
@@ -68,14 +68,14 @@ def mla_forward(params, x, positions, nope_dim=128, rope_dim=64,
 
 
 def mla_decode(params, x, cache_ckv, cache_kr, pos, nope_dim=128, rope_dim=64,
-               rope_theta=10000.0):
+               rope_theta=10000.0, *, eps):
     """Absorbed decode: score/context in the kv_lora latent space.
     x [B,1,D]; cache_ckv [B,T,kvl]; cache_kr [B,T,rope]."""
     B = x.shape[0]
     positions = jnp.full((B, 1), pos, jnp.int32)
     cos, sin = rope_angles(positions, rope_dim, rope_theta)
-    qn, qr = _queries(params, x, cos, sin, nope_dim)                # [B,1,H,*]
-    ckv_t = rms_norm(params["kv_norm"], x @ params["wdkv"])         # [B,1,kvl]
+    qn, qr = _queries(params, x, cos, sin, nope_dim, eps)           # [B,1,H,*]
+    ckv_t = rms_norm(params["kv_norm"], x @ params["wdkv"], eps)    # [B,1,kvl]
     kr_t = apply_rope((x @ params["wkr"])[:, :, None, :], cos, sin)[:, :, 0, :]
     cache_ckv = jax.lax.dynamic_update_slice_in_dim(
         cache_ckv, ckv_t.astype(cache_ckv.dtype), pos, 1)

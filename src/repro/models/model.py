@@ -96,6 +96,12 @@ class ModelConfig:
         return self.family == "xlstm"
 
     @property
+    def masks_padding(self) -> bool:
+        """Whether prefill and decode mask a left-padded prompt (per-row
+        ``lengths``), so that prompts of different lengths share a batch."""
+        return self.family in ("dense", "moe", "vlm", "audio")
+
+    @property
     def supports_long_context(self) -> bool:
         return self.family in ("hybrid", "xlstm")
 
@@ -271,6 +277,10 @@ class Model:
         return bool(self.cfg.slstm_every) and i % self.cfg.slstm_every == 1
 
     # ------------------------------------------------------------- embedding ----
+    def _norm(self, p, x):
+        """RMSNorm at the model's own epsilon."""
+        return L.rms_norm(p, x, self.cfg.rms_eps)
+
     def _embed(self, params, batch):
         cfg = self.cfg
         if cfg.family == "audio":
@@ -289,22 +299,25 @@ class Model:
                 batch["patch_embeds"].astype(x.dtype))
         return L.lsc(x.astype(cfg.dtype), "batch", "seq", None)
 
-    def _rope(self, batch, S, pos_offset=0):
+    def _rope(self, batch, S, start=None):
+        """RoPE angles for ``S`` positions; with ``start`` [B] each row's
+        positions count from 0 at its first valid slot (left padding)."""
         cfg = self.cfg
+        pos = jnp.arange(S)[None, :]
+        if start is not None:
+            pos = pos - start[:, None]
         if cfg.family == "vlm":
             if "positions3" in batch:
                 pos3 = batch["positions3"]
             else:
-                pos = pos_offset + jnp.arange(S)
-                pos3 = jnp.broadcast_to(pos[None, :, None], (1, S, 3))
+                pos3 = jnp.broadcast_to(pos[..., None], pos.shape + (3,))
             return L.mrope_angles(pos3, cfg.head_dim, cfg.mrope_sections,
                                   cfg.rope_theta)
-        pos = pos_offset + jnp.arange(S)
         return L.rope_angles(pos, cfg.head_dim, cfg.rope_theta)
 
     def _unembed(self, params, x):
         cfg = self.cfg
-        x = L.rms_norm(params["final_norm"], x)
+        x = self._norm(params["final_norm"], x)
         if cfg.family == "audio":
             logits = jnp.einsum("bsd,kdv->bskv", x, params["heads"])
         else:
@@ -327,24 +340,24 @@ class Model:
                 positions = jnp.arange(S)
 
                 def block(x, lp):
-                    h = MLA.mla_forward(lp["attn"], L.rms_norm(lp["ln1"], x), positions,
+                    h = MLA.mla_forward(lp["attn"], self._norm(lp["ln1"], x), positions,
                                         cfg.nope_head_dim, cfg.rope_head_dim,
                                         cfg.rope_theta, cfg.q_chunk, cfg.kv_chunk,
-                                        unroll=cfg.unroll_attention)
+                                        unroll=cfg.unroll_attention, eps=cfg.rms_eps)
                     x = x + h
-                    m, aux = MOE.moe_forward(lp["moe"], L.rms_norm(lp["ln2"], x),
+                    m, aux = MOE.moe_forward(lp["moe"], self._norm(lp["ln2"], x),
                                              cfg.top_k, cfg.capacity_factor)
                     x = x + m
                     x = L.lsc(x, "batch", "act_seq", None)
                     return x, aux
 
                 def block0(x, lp):
-                    h = MLA.mla_forward(lp["attn"], L.rms_norm(lp["ln1"], x), positions,
+                    h = MLA.mla_forward(lp["attn"], self._norm(lp["ln1"], x), positions,
                                         cfg.nope_head_dim, cfg.rope_head_dim,
                                         cfg.rope_theta, cfg.q_chunk, cfg.kv_chunk,
-                                        unroll=cfg.unroll_attention)
+                                        unroll=cfg.unroll_attention, eps=cfg.rms_eps)
                     x = x + h
-                    x = x + L.mlp_forward(lp["mlp"], L.rms_norm(lp["ln2"], x))
+                    x = x + L.mlp_forward(lp["mlp"], self._norm(lp["ln2"], x))
                     return x
 
                 x = _remat(block0, cfg)(x, params["layer0"])
@@ -352,11 +365,11 @@ class Model:
                 aux_total = auxs
             elif fam == "moe":
                 def block(x, lp):
-                    h = L.gqa_forward(lp["attn"], L.rms_norm(lp["ln1"], x), cos, sin,
+                    h = L.gqa_forward(lp["attn"], self._norm(lp["ln1"], x), cos, sin,
                                       q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
                                       unroll=cfg.unroll_attention)
                     x = x + h
-                    m, aux = MOE.moe_forward(lp["moe"], L.rms_norm(lp["ln2"], x),
+                    m, aux = MOE.moe_forward(lp["moe"], self._norm(lp["ln2"], x),
                                              cfg.top_k, cfg.capacity_factor)
                     x = x + m
                     x = L.lsc(x, "batch", "act_seq", None)
@@ -365,11 +378,11 @@ class Model:
                 x, aux_total = self._run_stack(block, x, params["layers"], cfg.n_layers)
             else:
                 def block(x, lp):
-                    h = L.gqa_forward(lp["attn"], L.rms_norm(lp["ln1"], x), cos, sin,
+                    h = L.gqa_forward(lp["attn"], self._norm(lp["ln1"], x), cos, sin,
                                       q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
                                       unroll=cfg.unroll_attention)
                     x = x + h
-                    x = x + L.mlp_forward(lp["mlp"], L.rms_norm(lp["ln2"], x))
+                    x = x + L.mlp_forward(lp["mlp"], self._norm(lp["ln2"], x))
                     x = L.lsc(x, "batch", "act_seq", None)
                     return x, jnp.zeros((), jnp.float32)
 
@@ -389,9 +402,10 @@ class Model:
 
                 def mblock(x, lp=lp):
                     return x + SSM.mamba2_forward(lp["mamba"],
-                                                  L.rms_norm(lp["norm"], x),
+                                                  self._norm(lp["norm"], x),
                                                   cfg.ssm_chunk,
-                                                  decay_dtype=cfg.ssd_decay_dtype)
+                                                  decay_dtype=cfg.ssd_decay_dtype,
+                                                  eps=cfg.rms_eps)
 
                 x = _remat(mblock, cfg)(x)
             return self._unembed(params, x), jnp.zeros((), jnp.float32)
@@ -402,14 +416,15 @@ class Model:
                 if self._is_slstm(i):
                     def sblock(x, lp=lp):
                         return x + XL.slstm_forward(lp["slstm"],
-                                                    L.rms_norm(lp["norm"], x),
-                                                    cfg.n_heads)
+                                                    self._norm(lp["norm"], x),
+                                                    cfg.n_heads, eps=cfg.rms_eps)
                     x = _remat(sblock, cfg)(x)
                 else:
                     def mblock(x, lp=lp):
                         return x + XL.mlstm_forward(lp["mlstm"],
-                                                    L.rms_norm(lp["norm"], x),
-                                                    cfg.n_heads, cfg.mlstm_chunk)
+                                                    self._norm(lp["norm"], x),
+                                                    cfg.n_heads, cfg.mlstm_chunk,
+                                                    eps=cfg.rms_eps)
                     x = _remat(mblock, cfg)(x)
             return self._unembed(params, x), jnp.zeros((), jnp.float32)
 
@@ -423,10 +438,10 @@ class Model:
 
         def block(x):
             h = jnp.concatenate([x, x0], axis=-1) @ proj
-            h = h + L.gqa_forward(sp["attn"], L.rms_norm(sp["ln1"], h), cos, sin,
+            h = h + L.gqa_forward(sp["attn"], self._norm(sp["ln1"], h), cos, sin,
                                   q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
                                   unroll=cfg.unroll_attention)
-            h = h + L.mlp_forward(sp["mlp"], L.rms_norm(sp["ln2"], h))
+            h = h + L.mlp_forward(sp["mlp"], self._norm(sp["ln2"], h))
             return x + h
 
         return _remat(block, cfg)(x)
@@ -471,9 +486,12 @@ class Model:
         if fam in ("dense", "vlm", "audio", "moe"):
             kv = (cfg.n_layers, batch_size, max_len, cfg.n_kv_heads, cfg.head_dim)
             axes = ("layers", "batch", "seq_kv", "kv_heads", None)
+            # pos: the next slot, shared by the batch; start: each row's
+            # first valid slot, after the left padding of a shorter prompt
             return {"k": Param(jnp.zeros(kv, dt), axes),
                     "v": Param(jnp.zeros(kv, dt), axes),
-                    "pos": Param(jnp.zeros((), jnp.int32), ())}
+                    "pos": Param(jnp.zeros((), jnp.int32), ()),
+                    "start": Param(jnp.zeros((batch_size,), jnp.int32), ("batch",))}
         if fam == "mla_moe":
             return {
                 "ckv": Param(jnp.zeros((cfg.n_layers, batch_size, max_len, cfg.kv_lora), dt),
@@ -529,7 +547,8 @@ class Model:
         B = x.shape[0]
 
         if fam in ("dense", "vlm", "audio", "moe"):
-            posb = jnp.full((B, 1), pos, jnp.int32)
+            start = cache["start"]
+            posb = (pos - start)[:, None]
             if fam == "vlm":
                 pos3 = jnp.broadcast_to(posb[..., None], (B, 1, 3))
                 cos, sin = L.mrope_angles(pos3, cfg.head_dim, cfg.mrope_sections,
@@ -540,7 +559,7 @@ class Model:
             if cfg.scan_layers:
                 def body(x, lp_and_cache):
                     lp, ck, cv = lp_and_cache
-                    h, ck, cv = self._decode_block(lp, x, ck, cv, pos, cos, sin)
+                    h, ck, cv = self._decode_block(lp, x, ck, cv, pos, cos, sin, start)
                     return h, (ck, cv)
 
                 x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], cache["k"],
@@ -551,7 +570,7 @@ class Model:
                 for i in range(cfg.n_layers):
                     lp = params["layers"][f"l{i}"]
                     x, ck, cv = self._decode_block(lp, x, cache["k"][i], cache["v"][i],
-                                                   pos, cos, sin)
+                                                   pos, cos, sin, start)
                     ks.append(ck)
                     vs.append(cv)
                 cache = {**cache, "k": jnp.stack(ks), "v": jnp.stack(vs), "pos": pos + 1}
@@ -559,14 +578,15 @@ class Model:
 
         if fam == "mla_moe":
             def mla_block(x, lp, ckv, kr, dense_mlp):
-                h, ckv, kr = MLA.mla_decode(lp["attn"], L.rms_norm(lp["ln1"], x),
+                h, ckv, kr = MLA.mla_decode(lp["attn"], self._norm(lp["ln1"], x),
                                             ckv, kr, pos, cfg.nope_head_dim,
-                                            cfg.rope_head_dim, cfg.rope_theta)
+                                            cfg.rope_head_dim, cfg.rope_theta,
+                                            eps=cfg.rms_eps)
                 x = x + h
                 if dense_mlp:
-                    x = x + L.mlp_forward(lp["mlp"], L.rms_norm(lp["ln2"], x))
+                    x = x + L.mlp_forward(lp["mlp"], self._norm(lp["ln2"], x))
                 else:
-                    m, _ = MOE.moe_forward(lp["moe"], L.rms_norm(lp["ln2"], x),
+                    m, _ = MOE.moe_forward(lp["moe"], self._norm(lp["ln2"], x),
                                            cfg.top_k, cfg.capacity_factor)
                     x = x + m
                 return x, ckv, kr
@@ -611,14 +631,15 @@ class Model:
                     proj = params["shared_proj"][f"s{site_no}"]
                     h = jnp.concatenate([x, x0], axis=-1) @ proj
                     a, ks[site_no], vs[site_no] = L.gqa_decode(
-                        sp["attn"], L.rms_norm(sp["ln1"], h), ks[site_no], vs[site_no],
+                        sp["attn"], self._norm(sp["ln1"], h), ks[site_no], vs[site_no],
                         pos, cos, sin)
                     h = h + a
-                    h = h + L.mlp_forward(sp["mlp"], L.rms_norm(sp["ln2"], h))
+                    h = h + L.mlp_forward(sp["mlp"], self._norm(sp["ln2"], h))
                     x = x + h
                     site_no += 1
-                out, s, cc = SSM.mamba2_decode(lp["mamba"], L.rms_norm(lp["norm"], x),
-                                               cache["ssm"][i], cache["conv"][i])
+                out, s, cc = SSM.mamba2_decode(lp["mamba"], self._norm(lp["norm"], x),
+                                               cache["ssm"][i], cache["conv"][i],
+                                               eps=cfg.rms_eps)
                 x = x + out
                 ssm_states.append(s)
                 conv_states.append(cc)
@@ -633,15 +654,15 @@ class Model:
                 lp = params["layers"][f"l{i}"]
                 if self._is_slstm(i):
                     st = tuple(cache["s_h"][si])
-                    out, st = XL.slstm_decode(lp["slstm"], L.rms_norm(lp["norm"], x),
-                                              st, cfg.n_heads)
+                    out, st = XL.slstm_decode(lp["slstm"], self._norm(lp["norm"], x),
+                                              st, cfg.n_heads, eps=cfg.rms_eps)
                     shs.append(jnp.stack(st))
                     si += 1
                 else:
                     out, (C, n) = XL.mlstm_decode(lp["mlstm"],
-                                                  L.rms_norm(lp["norm"], x),
+                                                  self._norm(lp["norm"], x),
                                                   (cache["C"][mi], cache["n"][mi]),
-                                                  cfg.n_heads)
+                                                  cfg.n_heads, eps=cfg.rms_eps)
                     Cs.append(C)
                     ns.append(n)
                     mi += 1
@@ -653,15 +674,15 @@ class Model:
 
         raise ValueError(fam)
 
-    def _decode_block(self, lp, x, ck, cv, pos, cos, sin):
+    def _decode_block(self, lp, x, ck, cv, pos, cos, sin, start):
         cfg = self.cfg
-        h, ck, cv = L.gqa_decode(lp["attn"], L.rms_norm(lp["ln1"], x), ck, cv, pos,
-                                 cos, sin)
+        h, ck, cv = L.gqa_decode(lp["attn"], self._norm(lp["ln1"], x), ck, cv, pos,
+                                 cos, sin, start)
         x = x + h
         if "mlp" in lp:
-            x = x + L.mlp_forward(lp["mlp"], L.rms_norm(lp["ln2"], x))
+            x = x + L.mlp_forward(lp["mlp"], self._norm(lp["ln2"], x))
         else:
-            m, _ = MOE.moe_forward(lp["moe"], L.rms_norm(lp["ln2"], x),
+            m, _ = MOE.moe_forward(lp["moe"], self._norm(lp["ln2"], x),
                                    cfg.top_k, cfg.capacity_factor)
             x = x + m
         return x, ck, cv
@@ -681,7 +702,14 @@ class Model:
         """Forward over the prompt, returning (last_logits, populated cache).
 
         For the attention families the K/V computed during the forward pass are
-        written into a fresh cache; SSM/xLSTM families return final states."""
+        written into a fresh cache; SSM/xLSTM families return final states.
+
+        ``batch["lengths"]`` [B] (default: all ``S``) gives each row's prompt
+        length, its prompt left-padded to ``S``.  The attention families
+        (``cfg.masks_padding``) mask each row's padding in prefill and every
+        later decode step, and count its positions from its first token, so
+        a row's logits do not depend on the rest of its batch; the others
+        take no padding."""
         cfg = self.cfg
         fam = cfg.family
         S = batch["tokens"].shape[-1]
@@ -690,20 +718,21 @@ class Model:
         cache = unbox(self.init_cache(B, max_len))
 
         if fam in ("dense", "vlm", "audio", "moe"):
+            start = S - batch.get("lengths", jnp.full((B,), S, jnp.int32))
             x = self._embed(params, batch)
-            cos, sin = self._rope(batch, S)
+            cos, sin = self._rope(batch, S, start)
             ks, vs = [], []
 
             def block(x, lp):
-                h, (k, v) = L.gqa_forward(lp["attn"], L.rms_norm(lp["ln1"], x), cos,
+                h, (k, v) = L.gqa_forward(lp["attn"], self._norm(lp["ln1"], x), cos,
                                           sin, q_chunk=cfg.q_chunk,
                                           kv_chunk=cfg.kv_chunk, return_kv=True,
-                                          unroll=cfg.unroll_attention)
+                                          unroll=cfg.unroll_attention, kv_start=start)
                 x = x + h
                 if "mlp" in lp:
-                    x = x + L.mlp_forward(lp["mlp"], L.rms_norm(lp["ln2"], x))
+                    x = x + L.mlp_forward(lp["mlp"], self._norm(lp["ln2"], x))
                 else:
-                    m, _ = MOE.moe_forward(lp["moe"], L.rms_norm(lp["ln2"], x),
+                    m, _ = MOE.moe_forward(lp["moe"], self._norm(lp["ln2"], x),
                                            cfg.top_k, cfg.capacity_factor)
                     x = x + m
                 x = L.lsc(x, "batch", "act_seq", None)
@@ -722,7 +751,8 @@ class Model:
             if pad:
                 ks = jnp.pad(ks, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
                 vs = jnp.pad(vs, ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0)))
-            cache.update({"k": ks, "v": vs, "pos": jnp.asarray(S, jnp.int32)})
+            cache.update({"k": ks, "v": vs, "pos": jnp.asarray(S, jnp.int32),
+                          "start": start.astype(jnp.int32)})
             return self._unembed(params, x)[:, -1], cache
 
         if fam == "mla_moe":
@@ -732,15 +762,15 @@ class Model:
 
             def block(x, lp, dense_mlp):
                 h, (ckv, kr) = MLA.mla_forward(
-                    lp["attn"], L.rms_norm(lp["ln1"], x), positions,
+                    lp["attn"], self._norm(lp["ln1"], x), positions,
                     cfg.nope_head_dim, cfg.rope_head_dim, cfg.rope_theta,
                     cfg.q_chunk, cfg.kv_chunk, return_cache=True,
-                    unroll=cfg.unroll_attention)
+                    unroll=cfg.unroll_attention, eps=cfg.rms_eps)
                 x = x + h
                 if dense_mlp:
-                    x = x + L.mlp_forward(lp["mlp"], L.rms_norm(lp["ln2"], x))
+                    x = x + L.mlp_forward(lp["mlp"], self._norm(lp["ln2"], x))
                 else:
-                    m, _ = MOE.moe_forward(lp["moe"], L.rms_norm(lp["ln2"], x),
+                    m, _ = MOE.moe_forward(lp["moe"], self._norm(lp["ln2"], x),
                                            cfg.top_k, cfg.capacity_factor)
                     x = x + m
                 return x, (ckv.astype(cfg.dtype), kr.astype(cfg.dtype))
@@ -782,12 +812,12 @@ class Model:
                     sp = params["shared_attn"]
                     proj = params["shared_proj"][f"s{site_no}"]
                     h = jnp.concatenate([x, x0], axis=-1) @ proj
-                    a, (k, v) = L.gqa_forward(sp["attn"], L.rms_norm(sp["ln1"], h),
+                    a, (k, v) = L.gqa_forward(sp["attn"], self._norm(sp["ln1"], h),
                                               cos, sin, q_chunk=cfg.q_chunk,
                                               kv_chunk=cfg.kv_chunk, return_kv=True,
                                               unroll=cfg.unroll_attention)
                     h = h + a
-                    h = h + L.mlp_forward(sp["mlp"], L.rms_norm(sp["ln2"], h))
+                    h = h + L.mlp_forward(sp["mlp"], self._norm(sp["ln2"], h))
                     x = x + h
                     pad = max_len - S
                     k, v = k.astype(cfg.dtype), v.astype(cfg.dtype)
@@ -798,9 +828,10 @@ class Model:
                     vs.append(v)
                     site_no += 1
                 out, (s, cc) = SSM.mamba2_forward(lp["mamba"],
-                                                  L.rms_norm(lp["norm"], x),
+                                                  self._norm(lp["norm"], x),
                                                   cfg.ssm_chunk, return_state=True,
-                                                  decay_dtype=cfg.ssd_decay_dtype)
+                                                  decay_dtype=cfg.ssd_decay_dtype,
+                                                  eps=cfg.rms_eps)
                 x = x + out
                 ssm_states.append(s)
                 conv_states.append(cc.astype(cfg.dtype))
@@ -815,14 +846,15 @@ class Model:
             for i in range(cfg.n_layers):
                 lp = params["layers"][f"l{i}"]
                 if self._is_slstm(i):
-                    out, st = XL.slstm_forward(lp["slstm"], L.rms_norm(lp["norm"], x),
-                                               cfg.n_heads, return_state=True)
+                    out, st = XL.slstm_forward(lp["slstm"], self._norm(lp["norm"], x),
+                                               cfg.n_heads, return_state=True,
+                                               eps=cfg.rms_eps)
                     shs.append(jnp.stack(st))
                 else:
                     out, (C, n) = XL.mlstm_forward(lp["mlstm"],
-                                                   L.rms_norm(lp["norm"], x),
+                                                   self._norm(lp["norm"], x),
                                                    cfg.n_heads, cfg.mlstm_chunk,
-                                                   return_state=True)
+                                                   return_state=True, eps=cfg.rms_eps)
                     Cs.append(C)
                     ns.append(n)
                 x = x + out

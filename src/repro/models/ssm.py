@@ -112,7 +112,7 @@ def _ssd_chunked(xh, B_, C_, dt, a, chunk: int, decay_dtype=jnp.float32):
 
 
 def mamba2_forward(params, x, chunk: int = 128, return_state: bool = False,
-                   decay_dtype=jnp.float32):
+                   decay_dtype=jnp.float32, *, eps: float):
     """x [B,S,D] -> [B,S,D] (full-sequence training/prefill path)."""
     z = jnp.einsum("bsd,df->bsf", x, params["wz"])
     xb = jnp.einsum("bsd,df->bsf", x, params["wx"])
@@ -129,7 +129,7 @@ def mamba2_forward(params, x, chunk: int = 128, return_state: bool = False,
     y, state = _ssd_chunked(xh, B_, C_, dt, a, chunk, decay_dtype=decay_dtype)
     y = y + xh * params["d_skip"].astype(xh.dtype)[None, None, :, None]
     y = y.reshape(*xb.shape)
-    y = rms_norm(params["out_norm"], y) * jax.nn.silu(z)
+    y = rms_norm(params["out_norm"], y, eps) * jax.nn.silu(z)
     out = jnp.einsum("bsf,fd->bsd", y, params["wo"])
     if return_state:
         # conv cache = last (W-1) x-branch inputs, pre-conv
@@ -140,7 +140,7 @@ def mamba2_forward(params, x, chunk: int = 128, return_state: bool = False,
     return out
 
 
-def mamba2_decode(params, x, state, conv_cache):
+def mamba2_decode(params, x, state, conv_cache, *, eps: float):
     """Single-step recurrence.  x [B,1,D]; state [B,H,N,P];
     conv_cache [B,W-1,Di] holds the previous pre-conv x-branch inputs."""
     f32 = jnp.float32
@@ -164,6 +164,6 @@ def mamba2_decode(params, x, state, conv_cache):
     y = jnp.einsum("bn,bhnp->bhp", C_, state)
     y = y + xh * params["d_skip"].astype(f32)[None, :, None]
     y = y.reshape(xb.shape).astype(x.dtype)
-    y = rms_norm(params["out_norm"], y) * jax.nn.silu(z)
+    y = rms_norm(params["out_norm"], y, eps) * jax.nn.silu(z)
     out = jnp.einsum("bf,fd->bd", y, params["wo"])[:, None, :]
     return out, state, new_conv_cache

@@ -134,28 +134,29 @@ def _mlstm_qkvg(params, xm, n_heads):
     return q, k, v, log_f, i_gate
 
 
-def mlstm_forward(params, x, n_heads: int, chunk: int = 128, return_state: bool = False):
+def mlstm_forward(params, x, n_heads: int, chunk: int = 128, return_state: bool = False,
+                  *, eps: float):
     up = jnp.einsum("bsd,df->bsf", x, params["w_up"])
     up = lsc(up, "batch", "seq", "ffn")
     xm, z = jnp.split(up, 2, axis=-1)
     q, k, v, log_f, i_gate = _mlstm_qkvg(params, xm, n_heads)
     y, state = _mlstm_chunked(q, k, v, log_f, i_gate, chunk)
     y = y.reshape(*xm.shape).astype(x.dtype)
-    y = rms_norm(params["out_norm"], y) * jax.nn.silu(z)
+    y = rms_norm(params["out_norm"], y, eps) * jax.nn.silu(z)
     out = jnp.einsum("bsf,fd->bsd", y, params["w_down"])
     if return_state:
         return out, state
     return out
 
 
-def mlstm_decode(params, x, state, n_heads: int):
+def mlstm_decode(params, x, state, n_heads: int, *, eps: float):
     C, n = state
     up = jnp.einsum("bsd,df->bsf", x, params["w_up"])
     xm, z = jnp.split(up, 2, axis=-1)
     q, k, v, log_f, i_gate = _mlstm_qkvg(params, xm[:, 0], n_heads)
     y, C, n = mlstm_cell_step(q, k, v, log_f, i_gate, C, n)
     y = y.reshape(xm[:, 0].shape).astype(x.dtype)
-    y = rms_norm(params["out_norm"], y) * jax.nn.silu(z[:, 0])
+    y = rms_norm(params["out_norm"], y, eps) * jax.nn.silu(z[:, 0])
     out = jnp.einsum("bf,fd->bd", y, params["w_down"])[:, None, :]
     return out, (C, n)
 
@@ -192,7 +193,8 @@ def slstm_cell_step(gx, r, h, c, n, n_heads):
     return h, c, n
 
 
-def slstm_forward(params, x, n_heads: int, return_state: bool = False):
+def slstm_forward(params, x, n_heads: int, return_state: bool = False,
+                  *, eps: float):
     B, S, d = x.shape
     dh = d // n_heads
     gx = jnp.einsum("bsd,de->bse", x, params["wx"]) + params["bias"]
@@ -207,14 +209,14 @@ def slstm_forward(params, x, n_heads: int, return_state: bool = False):
     h0 = jnp.zeros((B, n_heads, dh), jnp.float32)
     carry, hs = jax.lax.scan(step, (h0, h0, h0), gx.transpose(1, 0, 2))
     y = hs.transpose(1, 0, 2, 3).reshape(B, S, d).astype(x.dtype)
-    y = rms_norm(params["out_norm"], y)
+    y = rms_norm(params["out_norm"], y, eps)
     out = jnp.einsum("bsd,de->bse", y, params["wo"])
     if return_state:
         return out, carry
     return out
 
 
-def slstm_decode(params, x, state, n_heads: int):
+def slstm_decode(params, x, state, n_heads: int, *, eps: float):
     B, _, d = x.shape
     dh = d // n_heads
     h, c, n = state
@@ -222,6 +224,6 @@ def slstm_decode(params, x, state, n_heads: int):
     gx = gx.reshape(B, 4, n_heads, dh).transpose(0, 2, 3, 1).reshape(B, 4 * d)
     h, c, n = slstm_cell_step(gx, params["r"], h, c, n, n_heads)
     y = h.reshape(B, d).astype(x.dtype)
-    y = rms_norm(params["out_norm"], y)
+    y = rms_norm(params["out_norm"], y, eps)
     out = (y @ params["wo"])[:, None, :]
     return out, (h, c, n)

@@ -256,3 +256,47 @@ class WorkerMetrics:
             self.join_compile.observe(seconds)
         else:
             self.join_roundtrip.observe(seconds)
+
+
+class ServeMetrics:
+    """The serving engine's counters, booked once per batch (one
+    observation a request for the wait).  One instance per
+    ``ServingEngine``; the facade folds it into its workflow's scrape.
+
+    Prefill is timed until its logits are ready; the decode loop's total
+    is credited to its steps.  ``tf_serve_host_syncs_total`` counts the
+    device-to-host reads of the decode loop."""
+
+    __slots__ = ("registry", "prefill", "decode_step", "request_wait",
+                 "batches", "requests", "prompt_tokens", "pad_tokens",
+                 "tokens", "host_syncs")
+
+    def __init__(self) -> None:
+        r = self.registry = MetricsRegistry()
+        self.prefill = r.histogram("tf_serve_prefill_seconds")
+        self.decode_step = r.histogram("tf_serve_decode_step_seconds")
+        self.request_wait = r.histogram("tf_serve_request_wait_seconds")
+        self.batches = r.counter("tf_serve_batches_total")
+        self.requests = r.counter("tf_serve_requests_total")
+        self.prompt_tokens = r.counter("tf_serve_prompt_tokens_total")
+        self.pad_tokens = r.counter("tf_serve_pad_tokens_total")
+        self.tokens = r.counter("tf_serve_tokens_total")
+        self.host_syncs = r.counter("tf_serve_host_syncs_total")
+
+    def batch(self, prompt_lens: List[int], padded_len: int, waits: List[float],
+              prefill_s: float, steps: int, decode_s: float, tokens: int,
+              host_syncs: int) -> None:
+        """One served batch: its unpadded prompt lengths, padded to
+        ``padded_len``; the waits of the requests that carry a bus stamp;
+        prefill seconds; ``steps`` decode steps in ``decode_s`` seconds;
+        ``tokens`` generated and ``host_syncs`` reads made."""
+        self.batches.inc()
+        self.requests.inc(len(prompt_lens))
+        self.prompt_tokens.inc(sum(prompt_lens))
+        self.pad_tokens.inc(len(prompt_lens) * padded_len - sum(prompt_lens))
+        self.tokens.inc(tokens)
+        self.host_syncs.inc(host_syncs)
+        self.prefill.observe(prefill_s)
+        self.decode_step.observe_batch(steps, decode_s)
+        for w in waits:
+            self.request_wait.observe(w)

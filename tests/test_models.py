@@ -102,14 +102,14 @@ def test_mamba2_chunked_equals_recurrent():
     d, di, N, hd = 16, 32, 8, 8
     p = unbox(mamba2_init(keys, d, di, N, hd))
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 16, d), jnp.float32) * 0.5
-    y_chunked, (state, conv) = mamba2_forward(p, x, chunk=4, return_state=True)
+    y_chunked, (state, conv) = mamba2_forward(p, x, chunk=4, return_state=True, eps=1e-5)
     # step the recurrence token by token
     W = p["conv_w"].shape[0]
     st = jnp.zeros((2, di // hd, N, hd), jnp.float32)
     cc = jnp.zeros((2, W - 1, di), jnp.float32)
     outs = []
     for t in range(16):
-        o, st, cc = mamba2_decode(p, x[:, t:t + 1], st, cc)
+        o, st, cc = mamba2_decode(p, x[:, t:t + 1], st, cc, eps=1e-5)
         outs.append(o)
     y_step = jnp.concatenate(outs, axis=1)
     assert jnp.allclose(y_chunked, y_step, atol=2e-4), float(
@@ -122,14 +122,14 @@ def test_mlstm_chunked_equals_recurrent():
     d, H = 16, 4
     p = unbox(mlstm_init(keys, d, H, expand=2))
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 12, d), jnp.float32) * 0.5
-    y_chunked, (C, n) = mlstm_forward(p, x, H, chunk=4, return_state=True)
+    y_chunked, (C, n) = mlstm_forward(p, x, H, chunk=4, return_state=True, eps=1e-5)
     di = 2 * d
     Dh = di // H
     Cs = jnp.zeros((2, H, Dh, Dh), jnp.float32)
     ns = jnp.zeros((2, H, Dh), jnp.float32)
     outs = []
     for t in range(12):
-        o, (Cs, ns) = mlstm_decode(p, x[:, t:t + 1], (Cs, ns), H)
+        o, (Cs, ns) = mlstm_decode(p, x[:, t:t + 1], (Cs, ns), H, eps=1e-5)
         outs.append(o)
     y_step = jnp.concatenate(outs, axis=1)
     assert jnp.allclose(y_chunked, y_step, atol=2e-4), float(
@@ -142,11 +142,11 @@ def test_slstm_forward_equals_decode():
     d, H = 16, 4
     p = unbox(slstm_init(keys, d, H))
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 10, d), jnp.float32) * 0.5
-    y_full, state = slstm_forward(p, x, H, return_state=True)
+    y_full, state = slstm_forward(p, x, H, return_state=True, eps=1e-5)
     st = tuple(jnp.zeros((2, H, d // H), jnp.float32) for _ in range(3))
     outs = []
     for t in range(10):
-        o, st = slstm_decode(p, x[:, t:t + 1], st, H)
+        o, st = slstm_decode(p, x[:, t:t + 1], st, H, eps=1e-5)
         outs.append(o)
     y_step = jnp.concatenate(outs, axis=1)
     assert jnp.allclose(y_full, y_step, atol=2e-4)
@@ -192,7 +192,7 @@ def test_mamba2_bf16_decay_close_to_fp32():
     keys = KeyGen(jax.random.PRNGKey(11))
     p = unbox(mamba2_init(keys, 32, 64, 16, 16))
     x = jax.random.normal(jax.random.PRNGKey(12), (2, 64, 32), jnp.float32) * 0.5
-    y32 = mamba2_forward(p, x, chunk=16)
-    y16 = mamba2_forward(p, x, chunk=16, decay_dtype=jnp.bfloat16)
+    y32 = mamba2_forward(p, x, chunk=16, eps=1e-5)
+    y16 = mamba2_forward(p, x, chunk=16, decay_dtype=jnp.bfloat16, eps=1e-5)
     rel = float(jnp.abs(y32 - y16).max() / (jnp.abs(y32).max() + 1e-9))
     assert rel < 0.05, rel
